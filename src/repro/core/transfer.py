@@ -19,9 +19,10 @@ many *transport calls* the cost model prices (``num_calls``), never in Python
 loop structure; the dispatch count is 1 per non-empty plan by construction.
 
 On real TPU hardware each descriptor row lowers to one page DMA inside the
-single dispatch (same-pod ICI) or one DCN send; on this CPU container the
-kernel runs in interpret mode as a faithful data-plane copy and the *latency*
-is priced by ``core.costmodel``.
+single dispatch (same-pod ICI) or one DCN send; on a CPU backend the
+kernel runs in interpret mode as a faithful data-plane copy (see
+``repro.kernels.interpret_mode``) and the *latency* is priced by
+``core.costmodel``.
 
 The TransferBackend protocol
 ----------------------------
@@ -76,6 +77,7 @@ from repro.core import layout as L
 from repro.core.alignment import AlignmentResult, align
 from repro.core.costmodel import TransportProfile
 from repro.core.segments import Segment, blocks_to_segments
+from repro.kernels import interpret_mode
 from repro.kernels.kv_gather import kv_transfer
 
 Schedule = Literal["layerwise", "blockwise", "flowkv"]
@@ -168,12 +170,6 @@ def fine_page_rows(coarse_pages: np.ndarray, block_size: int,
     rows = (coarse_pages.astype(np.int64)[:, None, None] * block_size
             + t[None, :, None]) * local_heads + h[None, None, :]
     return rows.reshape(-1).astype(np.int32)
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode everywhere except real TPU backends, where the
-    kernel compiles to Mosaic (mirrors the donation check in _get_executor)."""
-    return jax.default_backend() != "tpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -528,7 +524,7 @@ class TransferEngine:
             raise ValueError("src/dst pools must agree on layer count")
         if self.src_spec.payload != self.dst_spec.payload:
             raise ValueError("src/dst pools must agree on page payload")
-        self.interpret = default_interpret() if interpret is None else interpret
+        self.interpret = interpret_mode(interpret)
         self.planner = TransferPlanner(src_spec)
         self.num_dispatches = 0
 
@@ -579,7 +575,7 @@ class ShardedTransferEngine:
         self.dst_spec = dst_spec
         self.src_shard = src_shard
         self.dst_shard = dst_shard
-        self.interpret = default_interpret() if interpret is None else interpret
+        self.interpret = interpret_mode(interpret)
         self.planner = TransferPlanner(src_spec)
         self.num_dispatches = 0
 

@@ -276,8 +276,6 @@ def sharded_decode_step_paged(shards: Sequence[Params], cfg: ModelConfig,
     from repro.kernels.kv_gather import kv_append_tokens
     from repro.kernels.paged_attention import paged_decode_attention
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     x = embed(token[:, None], shards[0]["embed"], scale=cfg.embed_scale)
     position = lengths
     num_layers = pools[0].shape[1]

@@ -19,11 +19,14 @@ in production; hd is the MXU lane dim.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -80,7 +83,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, q_blk: int = 128, k_blk: int = 128,
-                  q_offset: int = 0, interpret: bool = True) -> jax.Array:
+                  q_offset: int = 0, interpret: Optional[bool] = None) -> jax.Array:
     """q (B,S,H,hd); k/v (B,T,KV,hd) -> (B,S,H,hd). S/T divisible by blocks.
 
     T == S with ``q_offset=0`` is ordinary causal prefill. T > S with
@@ -126,6 +129,6 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((q_blk, g), jnp.float32),
             pltpu.VMEM((q_blk, g, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qr, kr, vr)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, s, h, hd)

@@ -10,6 +10,7 @@ frontier, so they never contribute.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,7 @@ from repro.kernels.flash_prefill.flash_prefill import flash_prefill
                                              "q_offset", "interpret"))
 def flash_prefill_op(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      causal: bool = True, q_blk: int = 128, k_blk: int = 128,
-                     q_offset: int = 0, interpret: bool = True) -> jax.Array:
+                     q_offset: int = 0, interpret: Optional[bool] = None) -> jax.Array:
     b, s, h, hd = q.shape
     t = k.shape[1]
     assert t == s + q_offset, "keys must cover prefix (q_offset) + queries"

@@ -59,10 +59,8 @@ def kv_append_tokens(pool: jax.Array, block_tables: jax.Array,
     positions (B,) int32 — the slot each request's token occupies;
     k_new / v_new (L, B, KV, hd). Returns the updated pool (aliased/donated
     through ``kv_transfer``; untouched slots keep their contents).
-    ``interpret=None`` resolves by backend (compiled Mosaic on TPU).
+    ``interpret=None`` resolves by backend (``repro.kernels.interpret_mode``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nb, L, two, payload = pool.shape
     tok_payload = payload // block_size                # KV * hd
     staging = stage_tokens(k_new, v_new).astype(pool.dtype)

@@ -15,10 +15,14 @@ would need L x 2 grid steps per block.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(ids_ref, pool_ref, out_ref):
@@ -27,7 +31,7 @@ def _kernel(ids_ref, pool_ref, out_ref):
 
 
 def kv_gather(pool: jax.Array, block_ids: jax.Array, *,
-              interpret: bool = True) -> jax.Array:
+              interpret: Optional[bool] = None) -> jax.Array:
     """pool (nb, L, 2, payload); block_ids (n,) int32 -> (n, L, 2, payload)."""
     nb, L, two, payload = pool.shape
     n = block_ids.shape[0]
@@ -43,5 +47,5 @@ def kv_gather(pool: jax.Array, block_ids: jax.Array, *,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, L, two, payload), pool.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_ids, pool)

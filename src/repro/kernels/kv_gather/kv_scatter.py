@@ -8,10 +8,14 @@ without a second pool allocation.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(ids_ref, staging_ref, pool_ref, out_ref):
@@ -20,7 +24,7 @@ def _kernel(ids_ref, staging_ref, pool_ref, out_ref):
 
 
 def kv_scatter(pool: jax.Array, block_ids: jax.Array, staging: jax.Array, *,
-               interpret: bool = True) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     """pool (nb, L, 2, payload); block_ids (n,) int32; staging (n, L, 2, payload)."""
     nb, L, two, payload = pool.shape
     n = block_ids.shape[0]
@@ -40,5 +44,5 @@ def kv_scatter(pool: jax.Array, block_ids: jax.Array, staging: jax.Array, *,
         # operand indices include the scalar-prefetch table: pool is operand 2
         # and aliases output 0 (in-place pool update / donation).
         input_output_aliases={2: 0},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_ids.astype(jnp.int32), staging, pool)

@@ -6,20 +6,34 @@ page ids — and the whole plan executes as ONE kernel dispatch, regardless of
 schedule (layerwise / blockwise / flowkv). Schedules differ only in how many
 *transport calls* the cost model prices, never in Python loop structure.
 
-Both pools are viewed as flat page tables ``(num_pages, payload)`` where one
-page is one (block, layer, k/v) slice — the finest unit any schedule moves.
-The two page-id tables are scalar-prefetched so the grid's index maps can
-compute each page DMA's source and destination before the body runs: the
-compiled artifact *is* the descriptor table. The destination pool is aliased
-to the output (donated under ``jax.jit``), so pages not named by the table
-keep their previous contents and no second pool allocation is made.
+Both pools are viewed as page tables ``(num_pages, rows, lanes)`` where one
+page is one (block, layer, k/v) slice — the finest unit any schedule moves —
+and ``rows * lanes`` is its payload (``lanes = 128`` whenever the payload
+allows it). Each grid step moves one whole page, so the block's last two
+dims equal the array's, which is what Mosaic's (8, 128) tiling rule asks of
+a block. The two page-id tables are scalar-prefetched so the grid's index
+maps can compute each page DMA's source and destination before the body
+runs: the compiled artifact *is* the descriptor table. The destination pool
+is aliased to the output (donated under ``jax.jit``), so pages not named by
+the table keep their previous contents and no second pool allocation is
+made.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
+
+
+def _page_view(pool: jax.Array, payload: int) -> jax.Array:
+    """``pool`` as ``(num_pages, rows, lanes)`` pages of ``payload`` elements."""
+    lanes = 128 if payload % 128 == 0 else payload
+    return pool.reshape(-1, payload // lanes, lanes)
 
 
 def _kernel(src_pages_ref, dst_pages_ref, src_ref, dst_ref, out_ref):
@@ -29,11 +43,11 @@ def _kernel(src_pages_ref, dst_pages_ref, src_ref, dst_ref, out_ref):
 
 def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
                 src_pages: jax.Array, dst_pages: jax.Array, *,
-                interpret: bool = True) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """Execute one descriptor table in one dispatch.
 
     ``src_pool`` / ``dst_pool`` are paged KV pools in either layout — they are
-    flattened to ``(num_pages, payload)`` page tables internally, so the same
+    viewed as ``(num_pages, rows, lanes)`` page tables internally, so the same
     kernel serves FLOWKV (B, L, 2, H) and VLLM (L, 2, B, H) pools on either
     side. ``src_pages`` / ``dst_pages`` are equal-length int32 page-id tables.
     Returns the updated destination pool (dst is aliased to the output).
@@ -42,17 +56,18 @@ def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
     if dst_pool.shape[-1] != payload:
         raise ValueError(
             f"src/dst page payloads differ: {payload} vs {dst_pool.shape[-1]}")
-    src_flat = src_pool.reshape(-1, payload)
-    dst_flat = dst_pool.reshape(-1, payload)
+    src_flat = _page_view(src_pool, payload)
+    dst_flat = _page_view(dst_pool, payload)
+    page = (None, *dst_flat.shape[1:])
     n = src_pages.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, payload), lambda i, sp, dp: (sp[i], 0)),
-            pl.BlockSpec((1, payload), lambda i, sp, dp: (dp[i], 0)),
+            pl.BlockSpec(page, lambda i, sp, dp: (sp[i], 0, 0)),
+            pl.BlockSpec(page, lambda i, sp, dp: (dp[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, payload), lambda i, sp, dp: (dp[i], 0)),
+        out_specs=pl.BlockSpec(page, lambda i, sp, dp: (dp[i], 0, 0)),
     )
     out_flat = pl.pallas_call(
         _kernel,
@@ -61,7 +76,7 @@ def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
         # operand indices include the two scalar-prefetch tables: dst_flat is
         # operand 3 and aliases output 0 (in-place pool update / donation).
         input_output_aliases={3: 0},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32),
       src_flat, dst_flat)
     return out_flat.reshape(dst_pool.shape)

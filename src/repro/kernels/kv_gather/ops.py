@@ -12,16 +12,11 @@ from repro.kernels.kv_gather.kv_scatter import kv_scatter
 from repro.kernels.kv_gather.kv_transfer import kv_transfer
 
 
-def _resolve(interpret: Optional[bool]) -> bool:
-    # interpret everywhere except real TPU backends (compiled Mosaic there)
-    return jax.default_backend() != "tpu" if interpret is None else interpret
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kv_gather_op(pool: jax.Array, block_ids: jax.Array, *,
                  interpret: Optional[bool] = None) -> jax.Array:
     return kv_gather(pool, block_ids.astype(jnp.int32),
-                     interpret=_resolve(interpret))
+                     interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -29,7 +24,7 @@ def kv_scatter_op(pool: jax.Array, block_ids: jax.Array, staging: jax.Array, *,
                   interpret: Optional[bool] = None) -> jax.Array:
     """Receiver side: place staged pages into local blocks (one dispatch)."""
     return kv_scatter(pool, block_ids.astype(jnp.int32), staging,
-                      interpret=_resolve(interpret))
+                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -38,4 +33,4 @@ def kv_transfer_op(src_pool: jax.Array, dst_pool: jax.Array,
                    interpret: Optional[bool] = None) -> jax.Array:
     """One fused descriptor-table dispatch (see ``kv_transfer``)."""
     return kv_transfer(src_pool, dst_pool, src_pages.astype(jnp.int32),
-                       dst_pages.astype(jnp.int32), interpret=_resolve(interpret))
+                       dst_pages.astype(jnp.int32), interpret=interpret)

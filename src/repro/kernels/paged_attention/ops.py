@@ -2,7 +2,7 @@
 
 ``paged_decode_attention_op`` takes the full FlowKV pool and a layer index,
 slices that layer's contiguous page plane, and runs the kernel. On TPU the
-call compiles to a Mosaic kernel; on this CPU container ``interpret=True``
+call compiles to a Mosaic kernel; on a CPU backend the Pallas interpreter
 executes the same kernel body for correctness (tests sweep shapes/dtypes
 against ``ref.py``).
 
@@ -13,6 +13,7 @@ covers the whole layer stack plus the fused KV append.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,8 @@ from repro.kernels.paged_attention.paged_attention import paged_decode_attention
                                              "return_stats"))
 def paged_decode_attention_op(q: jax.Array, pool: jax.Array, layer,
                               block_tables: jax.Array, lengths: jax.Array,
-                              *, block_size: int, interpret: bool = True,
+                              *, block_size: int,
+                              interpret: Optional[bool] = None,
                               return_stats: bool = False):
     """q (B,H,hd); pool (nb, L, 2, payload) FlowKV layout; layer scalar."""
     pages = jax.lax.dynamic_index_in_dim(pool, layer, axis=1, keepdims=False)

@@ -7,6 +7,13 @@ contiguous slice ``pages = pool[:, layer]`` of shape ``(nb, 2, payload)``
 and *one DMA per page* stages a block's K AND V for this layer into VMEM —
 no per-(layer, k/v) descriptors, mirroring the transfer-path win.
 
+A page's payload is slot-major ``(block_size, KV, hd)``. The wrapper views
+it kv-head-major, ``(nb, 2, KV, block_size, hd)``, in XLA (where the
+transpose fuses into the layer slice) and presents ``q`` as
+``(B, KV, G, hd)``, so every block's last two dims are whole array dims
+(Mosaic's tiling rule) and both contractions batch over the leading
+kv-head axis. No reshape happens inside the kernel.
+
 Grid: ``(B, max_blocks)`` — the page dim iterates sequentially (TPU minor
 grid dim), maintaining an online-softmax accumulator in VMEM scratch per
 sequence. Page indirection uses scalar-prefetched block tables in the
@@ -14,9 +21,8 @@ BlockSpec index_map, so the pipeline prefetches page ``i+1`` while page
 ``i`` is being processed (the TPU analogue of overlapping transfer kernels
 with compute).
 
-Tiling: payload = block_size * KV * hd. With the default 32-token blocks and
-128-wide head_dim every MXU operand is lane-aligned (hd multiple of 128 for
-most archs; 64/160/256 variants still vector-friendly).
+Tiling: one page block is ``(2, KV, block_size, hd)``. With the default
+32-token blocks and a 128-wide head_dim every MXU operand is lane-aligned.
 
 ``return_stats=True`` additionally emits the per-(kv-head, group) online
 softmax state ``(m, l)`` so callers can merge EXTRA keys exactly — the
@@ -26,11 +32,14 @@ is not in the pool yet) without densifying any cached page.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -38,7 +47,7 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 def _kernel(block_tables_ref, lengths_ref,     # scalar prefetch
             q_ref, pages_ref,                  # VMEM inputs
             *refs,                             # VMEM outputs + scratch
-            block_size: int, num_kv: int, head_dim: int, return_stats: bool):
+            block_size: int, head_dim: int, return_stats: bool):
     if return_stats:
         o_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -58,19 +67,14 @@ def _kernel(block_tables_ref, lengths_ref,     # scalar prefetch
 
     @pl.when(start < length)
     def _process():
-        q = q_ref[0]                                   # (H, hd)
-        h = q.shape[0]
-        g = h // num_kv
-        page = pages_ref[0]                            # (2, payload)
-        k = page[0].reshape(block_size, num_kv, head_dim)
-        v = page[1].reshape(block_size, num_kv, head_dim)
-        qg = q.reshape(num_kv, g, head_dim)
+        qg = q_ref[...].astype(jnp.float32)            # (KV, G, hd)
+        k = pages_ref[0].astype(jnp.float32)           # (KV, bs, hd)
+        v = pages_ref[1].astype(jnp.float32)
         s = jax.lax.dot_general(
-            qg.astype(jnp.float32), k.astype(jnp.float32),
-            (((2,), (2,)), ((0,), (1,))),
+            qg, k, (((2,), (2,)), ((0,), (0,))),
         )                                              # (KV, G, bs)
         s = s / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size), 2)
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(pos < length, s, NEG_INF)
 
         m_prev = m_ref[...]                            # (KV, G)
@@ -82,8 +86,7 @@ def _kernel(block_tables_ref, lengths_ref,     # scalar prefetch
         scale = jnp.exp(m_prev - m_new)
         l_new = l_prev * scale + p.sum(axis=-1)
         pv = jax.lax.dot_general(
-            p, v.astype(jnp.float32),
-            (((2,), (0,)), ((0,), (1,))),
+            p, v, (((2,), (1,)), ((0,), (0,))),
         )                                              # (KV, G, hd)
         acc_ref[...] = acc_ref[...] * scale[..., None] + pv
         m_ref[...] = m_new
@@ -91,18 +94,17 @@ def _kernel(block_tables_ref, lengths_ref,     # scalar prefetch
 
     @pl.when(i == nb - 1)
     def _finalize():
-        h = q_ref.shape[1]
         denom = jnp.maximum(l_ref[...], 1e-30)[..., None]
-        out = (acc_ref[...] / denom).reshape(h, head_dim)
-        o_ref[0] = out.astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
         if return_stats:
-            m_out_ref[0] = m_ref[...]
-            l_out_ref[0] = l_ref[...]
+            m_out_ref[...] = m_ref[...]
+            l_out_ref[...] = l_ref[...]
 
 
 def paged_decode_attention(q: jax.Array, pages: jax.Array,
                            block_tables: jax.Array, lengths: jax.Array,
-                           *, block_size: int, interpret: bool = True,
+                           *, block_size: int,
+                           interpret: Optional[bool] = None,
                            return_stats: bool = False):
     """q (B,H,hd); pages (nb,2,payload); block_tables (B,maxb); lengths (B,).
 
@@ -113,14 +115,18 @@ def paged_decode_attention(q: jax.Array, pages: jax.Array,
     """
     b, h, hd = q.shape
     maxb = block_tables.shape[1]
-    payload = pages.shape[-1]
+    nb, two, payload = pages.shape
     num_kv = payload // (block_size * hd)
     g = h // num_kv
+    qg = q.reshape(b, num_kv, g, hd)
+    pages_kv = pages.reshape(nb, two, block_size, num_kv, hd
+                             ).transpose(0, 1, 3, 2, 4)
 
-    out_specs = [pl.BlockSpec((1, h, hd), lambda bb, i, bt, ln: (bb, 0, 0))]
-    out_shapes = [jax.ShapeDtypeStruct((b, h, hd), q.dtype)]
+    per_seq = lambda bb, i, bt, ln: (bb, 0, 0, 0)
+    out_specs = [pl.BlockSpec((None, num_kv, g, hd), per_seq)]
+    out_shapes = [jax.ShapeDtypeStruct((b, num_kv, g, hd), q.dtype)]
     if return_stats:
-        out_specs += [pl.BlockSpec((1, num_kv, g),
+        out_specs += [pl.BlockSpec((None, num_kv, g),
                                    lambda bb, i, bt, ln: (bb, 0, 0))] * 2
         out_shapes += [jax.ShapeDtypeStruct((b, num_kv, g), jnp.float32)] * 2
 
@@ -128,9 +134,9 @@ def paged_decode_attention(q: jax.Array, pages: jax.Array,
         num_scalar_prefetch=2,
         grid=(b, maxb),
         in_specs=[
-            pl.BlockSpec((1, h, hd), lambda bb, i, bt, ln: (bb, 0, 0)),
-            pl.BlockSpec((1, 2, payload),
-                         lambda bb, i, bt, ln: (bt[bb, i], 0, 0)),
+            pl.BlockSpec((None, num_kv, g, hd), per_seq),
+            pl.BlockSpec((None, two, num_kv, block_size, hd),
+                         lambda bb, i, bt, ln: (bt[bb, i], 0, 0, 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -139,15 +145,15 @@ def paged_decode_attention(q: jax.Array, pages: jax.Array,
             pltpu.VMEM((num_kv, g, hd), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, block_size=block_size,
-                               num_kv=num_kv, head_dim=hd,
+    kernel = functools.partial(_kernel, block_size=block_size, head_dim=hd,
                                return_stats=return_stats)
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        interpret=interpret,
-    )(block_tables, lengths, q, pages)
+        interpret=interpret_mode(interpret),
+    )(block_tables, lengths, qg, pages_kv)
+    out = outs[0].reshape(b, h, hd)
     if return_stats:
-        return outs[0], outs[1], outs[2]
-    return outs[0]
+        return out, outs[1], outs[2]
+    return out

@@ -3,14 +3,17 @@ primary launcher).
 
 Two modes:
 
-* ``--engine real``  — CPU-scale: real JAX compute through the PD cluster
-  (smoke-sized model) via the :class:`repro.serving.api.FlowKVClient`
-  streaming facade, token-correct generation, real FlowKV page transfers.
+* ``--engine real``  — real JAX compute through the PD cluster via the
+  :class:`repro.serving.api.FlowKVClient` streaming facade, token-correct
+  generation, real FlowKV page transfers. The smoke-sized config by
+  default; ``--full`` builds the published config (random bf16 weights
+  made from ``--seed``), the size to run on a TPU.
 * ``--engine sim``   — cluster-scale: discrete-event simulation driving the
   same control plane with calibrated hardware costs (A100/L20/H20/TPUv5e).
 
 Examples:
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --engine real --requests 8
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --engine real --full
     PYTHONPATH=src python -m repro.launch.serve --arch llama31-8b --engine sim \\
         --system flowkv --workload 10k --rps 1.0
 """
@@ -21,16 +24,18 @@ import json
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def run_real(args) -> dict:
     import jax
 
-    from repro.configs import get_smoke_config
+    from repro.configs import get_config, get_smoke_config
     from repro.models.api import get_model
     from repro.serving.api import FlowKVClient
     from repro.serving.request import SamplingParams
 
-    cfg = get_smoke_config(args.arch)
+    cfg = (get_config if args.full else get_smoke_config)(args.arch)
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
     client = FlowKVClient(cfg, params, num_prefill=args.num_prefill,
@@ -70,6 +75,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--engine", choices=("real", "sim"), default="real")
+    ap.add_argument("--full", action="store_true",
+                    help="real engine: the published config instead of the "
+                         "smoke-sized one")
     ap.add_argument("--system", default="flowkv")
     ap.add_argument("--schedule", default="flowkv",
                     choices=("flowkv", "layerwise", "blockwise"))
@@ -89,6 +97,7 @@ def main() -> None:
     ap.add_argument("--same-host", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     stats = run_real(args) if args.engine == "real" else run_sim(args)
     print(json.dumps(stats, indent=1, default=str))
 

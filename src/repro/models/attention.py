@@ -219,7 +219,7 @@ def decode_self_attention(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfi
 def decode_paged_self_attention(p: Dict[str, jax.Array], x: jax.Array,
                                 cfg: ModelConfig, pages: jax.Array,
                                 block_tables: jax.Array, position: jax.Array,
-                                *, interpret: bool = True
+                                *, interpret: Optional[bool] = None
                                 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Single-token decode directly against one layer's FlowKV page plane.
 
@@ -240,7 +240,7 @@ def decode_paged_self_attention(p: Dict[str, jax.Array], x: jax.Array,
 def decode_paged_attention_heads(p: Dict[str, jax.Array], x: jax.Array,
                                  cfg: ModelConfig, pages: jax.Array,
                                  block_tables: jax.Array, position: jax.Array,
-                                 *, interpret: bool = True
+                                 *, interpret: Optional[bool] = None
                                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """:func:`decode_paged_self_attention` minus the output projection.
 

@@ -289,8 +289,6 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jax.Array,
     """
     from repro.kernels.kv_gather import kv_append_tokens
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     x = embed(token[:, None], params["embed"], scale=cfg.embed_scale)
     position = lengths
     L = pool.shape[1]

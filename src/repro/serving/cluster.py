@@ -30,7 +30,7 @@ from repro.models.common import ModelConfig
 from repro.serving.engine import NodeEngine
 from repro.serving.host_tier import TierManager
 from repro.serving.request import Request, RequestState
-from repro.sim.hardware import HardwareProfile, TPU_V5E
+from repro.sim.hardware import HardwareProfile, local_hardware
 
 
 @dataclasses.dataclass
@@ -60,8 +60,8 @@ class PDCluster:
     def __init__(self, cfg: ModelConfig, params, *, num_prefill: int = 1,
                  num_decode: int = 1, num_blocks: int = 256,
                  allocator: str = "flowkv", transfer_schedule: str = "flowkv",
-                 hardware: Union[HardwareProfile,
-                                 Dict[int, HardwareProfile]] = TPU_V5E,
+                 hardware: Union[None, HardwareProfile,
+                                 Dict[int, HardwareProfile]] = None,
                  target: str = "tpu",
                  max_batch_tokens: int = 2048, hosts: Optional[Dict[int, int]] = None,
                  role_flip: bool = False, paged_decode: str = "auto",
@@ -143,6 +143,9 @@ class PDCluster:
         self.degraded_to_recompute = 0
         self.recoveries = 0
 
+        # nodes with no given profile get the chip this process runs on
+        if hardware is None or isinstance(hardware, dict):
+            default_hw = local_hardware()
         for i in range(num_prefill + num_decode):
             role = "prefill" if i < num_prefill else "decode"
             engine = NodeEngine(i, cfg, params, num_blocks=num_blocks,
@@ -155,9 +158,11 @@ class PDCluster:
             self.engines[i] = engine
             host = (hosts or {}).get(i, i)
             # heterogeneous fleets: hardware may be one profile for every
-            # node or a {node_id: profile} map (missing ids get TPU_V5E)
-            hw = hardware.get(i, TPU_V5E) if isinstance(hardware, dict) \
-                else hardware
+            # node or a {node_id: profile} map
+            if isinstance(hardware, dict):
+                hw = hardware.get(i, default_hw)
+            else:
+                hw = hardware or default_hw
             reuse = prefix_reuse and engine.supports_prefix_reuse
             self.controller.register_node(NodeHandle(
                 node_id=i, role=role, host_id=host, hardware=hw,

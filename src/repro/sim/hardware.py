@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 from repro.core.costmodel import (NCCL_ENI, IPC, TPU_DCN, TPU_ICI,
                                   TransportProfile, predicted_ttft_s)
 
@@ -28,6 +30,7 @@ class HardwareProfile:
     mfu_prefill: float = 0.55   # achievable fraction of peak in prefill
     mbu_decode: float = 0.60    # achievable fraction of HBM bw in decode
     step_overhead_s: float = 4e-3
+    device_kind: str = ""       # jax ``device_kind`` of this chip, if a TPU
 
     # -- step-time estimates --------------------------------------------------
     def prefill_time(self, flops: float) -> float:
@@ -58,11 +61,28 @@ H20 = HardwareProfile(  # compute-lean but bandwidth/memory-rich — paper's D-f
 TPU_V5E = HardwareProfile(
     name="TPUv5e",
     peak_flops=197e12, hbm_bandwidth=819e9, hbm_bytes=16 << 30,
-    intra_host=TPU_ICI, inter_host=TPU_DCN,
+    intra_host=TPU_ICI, inter_host=TPU_DCN, device_kind="TPU v5 lite",
 )
 
 PROFILES = {p.name: p for p in (A100, L20, H20, TPU_V5E)}
 ALIASES = {"a100": A100, "l20": L20, "h20": H20, "tpuv5e": TPU_V5E, "v5e": TPU_V5E}
+
+
+def local_hardware() -> HardwareProfile:
+    """Profile of the chip this process runs on.
+
+    On a TPU it is the profile whose ``device_kind`` matches the device's,
+    and a kind with no profile raises. Without an accelerator (the CPU
+    backend the tests run on) the runtime models its port target, TPU v5e.
+    """
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    for profile in PROFILES.values():
+        if profile.device_kind == dev.device_kind:
+            return profile
+    raise ValueError(f"no hardware profile for device kind "
+                     f"{dev.device_kind!r}; add one to repro.sim.hardware")
 
 
 def get_hardware(name: str) -> HardwareProfile:

@@ -487,6 +487,15 @@ def test_flash_prefill_suffix_mode_matches_oracle(s, t, blk):
 
 
 def test_model_prefill_suffix_bit_identical(small_model):
+    """Suffix prefill reproduces the full prefill's rows to float32 rounding.
+
+    Not bit for bit: XLA picks a matmul's blocking by its operand shapes,
+    so the 16-row projection of a suffix and the same rows of the 80-row
+    full-prompt projection round differently in the last bits (even layer
+    0's K differs). The bar is the float32 one instead: every value within
+    a few hundred units of float32 rounding (2**-24 relative) of the largest
+    magnitude, far below anything that moves a greedy token.
+    """
     cfg, params = small_model
     model = get_model(cfg)
     rng = np.random.RandomState(5)
@@ -496,6 +505,8 @@ def test_model_prefill_suffix_bit_identical(small_model):
     sl, sc = model.prefill_suffix(
         params, {"tokens": jnp.asarray([prompt[c:]], jnp.int32)},
         cache["k"][:, :, :c], cache["v"][:, :, :c])
-    assert jnp.array_equal(logits, sl)
-    assert jnp.array_equal(cache["k"][:, :, c:], sc["k"])
-    assert jnp.array_equal(cache["v"][:, :, c:], sc["v"])
+    for full, suffix in ((logits, sl), (cache["k"][:, :, c:], sc["k"]),
+                         (cache["v"][:, :, c:], sc["v"])):
+        full, suffix = np.asarray(full), np.asarray(suffix)
+        np.testing.assert_allclose(suffix, full, rtol=0,
+                                   atol=256 * 2.0 ** -24 * np.abs(full).max())

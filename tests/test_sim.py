@@ -153,3 +153,25 @@ def test_scenario_registry_complete():
         assert sc.name == name and sc.description
     with pytest.raises(ValueError, match="unknown scenario"):
         get_scenario("nope")
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("cpu", "cpu", "TPUv5e"),            # no accelerator: the modelled target
+    ("tpu", "TPU v5 lite", "TPUv5e"),    # the chip's own profile
+    ("tpu", "TPU v9 imaginary", None),   # a TPU with no profile raises
+])
+def test_local_hardware_by_device_kind(monkeypatch, platform, kind, expect):
+    import jax
+
+    from repro.sim import hardware
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice(platform, kind)])
+    if expect is None:
+        with pytest.raises(ValueError, match="no hardware profile"):
+            hardware.local_hardware()
+    else:
+        assert hardware.local_hardware().name == expect

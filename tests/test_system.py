@@ -122,7 +122,8 @@ def test_mini_multipod_dryrun():
 
         cfg = get_smoke_config("minitron-8b")
         model = get_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         state = ST.abstract_train_state(model)
         train_step, state_spec = ST.make_train_step(model, mesh, state["params"])
         specs, axes = input_specs(cfg, "train", 16, 8)
@@ -148,3 +149,19 @@ def test_mini_multipod_dryrun():
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["devices"] == 8
     assert result["loss"] > 0 and result["loss"] < 20
+
+
+def test_compile_cache_dir_honours_env(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise a fixed checkout
+    path. jax.config.update is captured, so the cache stays off here."""
+    from repro.launch import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/where")
+    assert compile_cache.enable_compile_cache() == "/some/where"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == str(pathlib.Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", path)]

@@ -130,5 +130,6 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((q_blk, g, hd), jnp.float32),
         ],
         interpret=interpret_mode(interpret),
+        name="flash_prefill",
     )(qr, kr, vr)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, s, h, hd)

@@ -67,5 +67,6 @@ def kv_append_tokens(pool: jax.Array, block_tables: jax.Array,
     ids = append_slot_ids(block_tables, positions, L, block_size)
     src = jnp.arange(staging.shape[0], dtype=jnp.int32)
     pool_view = pool.reshape(nb, L, 2, block_size, tok_payload)
-    out = kv_transfer(staging, pool_view, src, ids, interpret=interpret)
+    out = kv_transfer(staging, pool_view, src, ids, interpret=interpret,
+                      name="kv_append")
     return out.reshape(pool.shape)

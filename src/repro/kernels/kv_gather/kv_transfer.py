@@ -17,6 +17,12 @@ runs: the compiled artifact *is* the descriptor table. The destination pool
 is aliased to the output (donated under ``jax.jit``), so pages not named by
 the table keep their previous contents and no second pool allocation is
 made.
+
+Viewing a pool as pages is a relayout on the TPU (its tiled layout changes
+with the minor dims), so the views and their inverse run under the
+``pool_relayout`` named scope, and the kernel carries the caller's ``name``
+(``kv_transfer`` for a P->D plan, ``kv_append`` for the decode step's
+append): both are how the device trace attributes their time.
 """
 from __future__ import annotations
 
@@ -43,7 +49,8 @@ def _kernel(src_pages_ref, dst_pages_ref, src_ref, dst_ref, out_ref):
 
 def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
                 src_pages: jax.Array, dst_pages: jax.Array, *,
-                interpret: Optional[bool] = None) -> jax.Array:
+                interpret: Optional[bool] = None,
+                name: str = "kv_transfer") -> jax.Array:
     """Execute one descriptor table in one dispatch.
 
     ``src_pool`` / ``dst_pool`` are paged KV pools in either layout — they are
@@ -51,13 +58,15 @@ def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
     kernel serves FLOWKV (B, L, 2, H) and VLLM (L, 2, B, H) pools on either
     side. ``src_pages`` / ``dst_pages`` are equal-length int32 page-id tables.
     Returns the updated destination pool (dst is aliased to the output).
+    ``name`` names the kernel in the compiled program and the device trace.
     """
     payload = src_pool.shape[-1]
     if dst_pool.shape[-1] != payload:
         raise ValueError(
             f"src/dst page payloads differ: {payload} vs {dst_pool.shape[-1]}")
-    src_flat = _page_view(src_pool, payload)
-    dst_flat = _page_view(dst_pool, payload)
+    with jax.named_scope("pool_relayout"):
+        src_flat = _page_view(src_pool, payload)
+        dst_flat = _page_view(dst_pool, payload)
     page = (None, *dst_flat.shape[1:])
     n = src_pages.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -77,6 +86,8 @@ def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
         # operand 3 and aliases output 0 (in-place pool update / donation).
         input_output_aliases={3: 0},
         interpret=interpret_mode(interpret),
+        name=name,
     )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32),
       src_flat, dst_flat)
-    return out_flat.reshape(dst_pool.shape)
+    with jax.named_scope("pool_relayout"):
+        return out_flat.reshape(dst_pool.shape)

@@ -152,6 +152,7 @@ def paged_decode_attention(q: jax.Array, pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret_mode(interpret),
+        name="paged_attention",
     )(block_tables, lengths, qg, pages_kv)
     out = outs[0].reshape(b, h, hd)
     if return_stats:

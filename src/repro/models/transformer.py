@@ -178,7 +178,9 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
         h, aux_i, k, v = _layer_train(cfg, h, lp, positions)
         return (h, aux + aux_i), (k, v)
 
-    (x, _), (ks, vs) = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    with jax.named_scope("prefill_layers"):
+        (x, _), (ks, vs) = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                        params["layers"])
     x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params.get("unembed", params["embed"]))[:, 0]
     length = jnp.full((tokens.shape[0],), ks.shape[2], jnp.int32)
@@ -215,9 +217,10 @@ def prefill_suffix(params: Params, cfg: ModelConfig, tokens: jax.Array,
         ffn_out, aux_i = _ffn(lp, hn, cfg)
         return (h + ffn_out, aux + aux_i), (k, v)
 
-    (x, _), (ks, vs) = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], prefix_k, prefix_v))
+    with jax.named_scope("prefill_layers"):
+        (x, _), (ks, vs) = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)),
+            (params["layers"], prefix_k, prefix_v))
     x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params.get("unembed", params["embed"]))[:, 0]
     length = jnp.full((tokens.shape[0],), c + ks.shape[2], jnp.int32)
@@ -296,9 +299,12 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jax.Array,
     def body(h, inputs):
         lp, layer = inputs
         hn = rms_norm(h, lp["norm_attn"], cfg.norm_eps)
-        pages = jax.lax.dynamic_index_in_dim(pool, layer, axis=1, keepdims=False)
-        attn_out, (k_new, v_new) = A.decode_paged_self_attention(
-            lp, hn, cfg, pages, block_tables, position, interpret=interpret)
+        with jax.named_scope("paged_attention"):
+            pages = jax.lax.dynamic_index_in_dim(pool, layer, axis=1,
+                                                 keepdims=False)
+            attn_out, (k_new, v_new) = A.decode_paged_self_attention(
+                lp, hn, cfg, pages, block_tables, position,
+                interpret=interpret)
         h = h + attn_out
         hn = rms_norm(h, lp["norm_mlp"], cfg.norm_eps)
         ffn_out, _ = _ffn(lp, hn, cfg)
@@ -306,8 +312,9 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jax.Array,
 
     x, (ks, vs) = jax.lax.scan(
         body, x, (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-    pool = kv_append_tokens(pool, block_tables, position, ks, vs,
-                            block_size=cfg.block_size, interpret=interpret)
+    with jax.named_scope("kv_append"):
+        pool = kv_append_tokens(pool, block_tables, position, ks, vs,
+                                block_size=cfg.block_size, interpret=interpret)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params.get("unembed", params["embed"]))[:, 0]
     return logits, pool

@@ -4,11 +4,13 @@ models, and the cross-PR perf trajectory.
 The paper's headline numbers are *measured* claims; this package is what
 lets the reproduction measure honestly:
 
-* ``tracing``   — low-overhead per-request span recorder (queue / admission /
-                  prefill / transfer / decode / prefix_fetch) with both
-                  scheduler-clock and wall-clock timestamps, JSONL export,
-                  and ``attach_tracer`` to wire a recorder into a live
-                  ``PDCluster`` or ``ClusterSim``.
+* ``tracing``   — low-overhead span recorder: per-request lifecycle spans
+                  (queue / admission / prefill / transfer / decode /
+                  prefix_fetch) on both the scheduler clock and the wall
+                  clock, nested step spans on the profiler's clock,
+                  compile requests charged to the open span, JSONL export,
+                  and ``attach_tracer`` / ``detach_tracer`` to wire a
+                  recorder into a live ``PDCluster`` or ``ClusterSim``.
 * ``calibrate`` — fits ``TransportProfile`` / ``HardwareProfile``
                   coefficients from measured kernel timings and asserts a
                   sim-vs-real predicted-TTFT error bound (CI gate).
@@ -24,7 +26,7 @@ See ``docs/observability.md`` for the span taxonomy, trace format and the
 calibration workflow.
 """
 from repro.obs.tracing import (Span, SpanRecorder, Trace, attach_tracer,
-                               read_trace, write_trace)
+                               detach_tracer, read_trace, write_trace)
 
-__all__ = ["Span", "SpanRecorder", "Trace", "attach_tracer", "read_trace",
-           "write_trace"]
+__all__ = ["Span", "SpanRecorder", "Trace", "attach_tracer", "detach_tracer",
+           "read_trace", "write_trace"]

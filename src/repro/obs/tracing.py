@@ -1,8 +1,10 @@
-"""Per-request span tracing for the disaggregated runtime.
+"""Per-request and per-step span tracing for the disaggregated runtime.
 
 A :class:`Span` is one phase of one request's life — ``queue``,
 ``admission``, ``prefill``, ``transfer``, ``decode`` or ``prefix_fetch`` —
-stamped on BOTH timelines the system runs on:
+or one step of the serving loop (``cluster.step``, ``decode.step`` and the
+pieces of a prefill chunk or a transfer), stamped on BOTH timelines the
+system runs on:
 
 * ``start_cycle`` / ``end_cycle`` — the driving scheduler clock. In the
   real runtime (``PDCluster``) this is the cluster cycle counter; in the
@@ -12,8 +14,18 @@ stamped on BOTH timelines the system runs on:
   simulator leaves these ``None`` (its virtual data plane consumes no wall
   time worth attributing).
 
+Step spans come from :meth:`SpanRecorder.span`, a context manager that keeps
+a stack of open spans, so each span's ``parent_id`` names the span open
+around it, and that holds a ``jax.profiler.TraceAnnotation("flowkv." +
+name)`` while it is open: under ``jax.profiler.start_trace`` every step span
+lands on the profiler's host plane, on the device trace's clock. While a
+recorder is attached it also charges each backend compile request (a
+persistent-cache load included) to the innermost open span
+(:attr:`SpanRecorder.compiles`).
+
 The recorder is deliberately dumb — one list append per span, no locks, no
-I/O on the hot path — so tracing can stay on during benchmarks. Export is
+I/O on the hot path. With no recorder attached nothing is created: each
+emission site checks ``tracer is not None`` first. Export is
 line-oriented JSON (one header record, then request-shape records, then
 span records) so traces stream, diff and grep well; :func:`read_trace`
 validates the schema version and round-trips exactly
@@ -26,13 +38,15 @@ cluster or simulator with no constructor plumbing.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import pathlib
 import time
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
+from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple, Optional,
+                    Sequence, Union)
 
-TRACE_SCHEMA_VERSION = 5
+TRACE_SCHEMA_VERSION = 6
 
 # Schema history: v1 had the six lifecycle span kinds; v2 (chunked prefill +
 # layerwise overlap) added the fine-grained ``prefill_chunk`` and
@@ -40,9 +54,10 @@ TRACE_SCHEMA_VERSION = 5
 # ``failure`` / ``transfer_retry`` / ``recovery`` kinds; v4 (tiered KV)
 # added ``tier_demote`` / ``tier_promote``; v5 (sharded serving) added the
 # mesh-parallel transfer attrs (``src_tp`` / ``dst_tp`` / ``dispatches`` as
-# shard-pair counts) on existing span kinds. Each bump is additive, so
-# older traces still read.
-SUPPORTED_SCHEMAS = (1, 2, 3, 4, 5)
+# shard-pair counts) on existing span kinds; v6 (step spans) added
+# ``span_id`` / ``parent_id`` and the names of STEP_SPAN_NAMES. Each bump is
+# additive, so older traces still read.
+SUPPORTED_SCHEMAS = (1, 2, 3, 4, 5, 6)
 
 # The span taxonomy (docs/observability.md). Producers are free to add new
 # names — consumers must treat this as open — but these are the request
@@ -63,19 +78,48 @@ SPAN_NAMES = ("queue", "admission", "prefill", "prefill_chunk", "transfer",
               "failure", "transfer_retry", "recovery",
               "tier_demote", "tier_promote")
 
+# The step spans of the real runtime (docs/observability.md), each recorded
+# through SpanRecorder.span with its parent: ``cluster.step`` holds one
+# PDCluster cycle; ``prefill_chunk`` holds ``prefill.gather_prefix`` (suffix
+# chunks), ``prefill.forward``, ``prefill.write`` and ``prefill.sample`` (the
+# final chunk's argmax read); ``decode.step`` holds ``decode.prepare``
+# (block tables, padding, host->device inputs), ``decode.dispatch`` (the
+# jitted step) and ``decode.readback`` (the logits read and argmax);
+# ``transfer`` holds ``transfer.plan``, ``transfer.execute`` (the kernel
+# dispatch) and ``transfer.verify`` (the checksum). Host spans time the host:
+# a span that ends in a host read (``decode.readback``, ``prefill.sample``,
+# ``transfer.verify``) includes the wait for the device.
+STEP_SPAN_NAMES = ("cluster.step", "prefill.gather_prefix", "prefill.forward",
+                   "prefill.write", "prefill.sample", "decode.step",
+                   "decode.prepare", "decode.dispatch", "decode.readback",
+                   "transfer.plan", "transfer.execute", "transfer.verify")
+
+# The profiler annotation of a span is this prefix plus its name.
+ANNOTATION_PREFIX = "flowkv."
+
+# jax.monitoring event of one backend compile request; a load from the
+# persistent compilation cache reports one too.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# What an emission site enters when no recorder is attached: one shared,
+# stateless context, so an untraced run creates nothing per span.
+NO_SPAN = contextlib.nullcontext()
+
 
 @dataclasses.dataclass
 class Span:
     """One phase of one request, on both clocks (None = not applicable)."""
 
-    trace_id: int                        # request_id
-    name: str                            # see SPAN_NAMES
+    trace_id: int                        # request_id (-1: no one request)
+    name: str                            # see SPAN_NAMES / STEP_SPAN_NAMES
     start_cycle: Optional[float] = None
     end_cycle: Optional[float] = None
     start_wall_s: Optional[float] = None
     end_wall_s: Optional[float] = None
     node_id: Optional[int] = None
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    span_id: Optional[int] = None        # unique within one recorder
+    parent_id: Optional[int] = None      # the span open around this one
 
     def duration_cycles(self) -> Optional[float]:
         if self.start_cycle is None or self.end_cycle is None:
@@ -90,7 +134,7 @@ class Span:
     def to_record(self) -> Dict[str, Any]:
         rec = {"kind": "span", "trace_id": self.trace_id, "name": self.name}
         for key in ("start_cycle", "end_cycle", "start_wall_s", "end_wall_s",
-                    "node_id"):
+                    "node_id", "span_id", "parent_id"):
             val = getattr(self, key)
             if val is not None:
                 rec[key] = val
@@ -105,30 +149,112 @@ class Span:
             start_cycle=rec.get("start_cycle"), end_cycle=rec.get("end_cycle"),
             start_wall_s=rec.get("start_wall_s"),
             end_wall_s=rec.get("end_wall_s"),
-            node_id=rec.get("node_id"), attrs=dict(rec.get("attrs", {})))
+            node_id=rec.get("node_id"), attrs=dict(rec.get("attrs", {})),
+            span_id=rec.get("span_id"), parent_id=rec.get("parent_id"))
+
+
+class CompileCharge(NamedTuple):
+    """One backend compile request, charged to the span open when it ended."""
+
+    end_wall_s: float
+    seconds: float
+    span: Optional[str]         # innermost open span's name; None if none
 
 
 class SpanRecorder:
     """Append-only span sink with a monotonic wall clock.
 
     ``wall()`` is the ONE wall-clock source every producer shares, so spans
-    from different layers of the same process are comparable.
+    from different layers of the same process are comparable. Every span
+    gets a ``span_id``; one recorded while a :meth:`span` is open gets that
+    span's id as its ``parent_id``.
     """
 
     def __init__(self) -> None:
         self.spans: List[Span] = []
+        self.compiles: List[CompileCharge] = []
+        self._open: List[Span] = []
+        self._next_id = 0
+        self._listening = False
 
     def wall(self) -> float:
         return time.monotonic()
+
+    def _stamp(self, span: Span) -> Span:
+        span.span_id = self._next_id
+        self._next_id += 1
+        if self._open and span.parent_id is None:
+            span.parent_id = self._open[-1].span_id
+        return span
 
     def record(self, span: Span) -> None:
         self.spans.append(span)
 
     def emit(self, trace_id: int, name: str, **kw) -> Span:
         """Build-and-record in one call (the hot-path helper)."""
-        span = Span(trace_id=trace_id, name=name, **kw)
+        span = self._stamp(Span(trace_id=trace_id, name=name, **kw))
         self.spans.append(span)
         return span
+
+    @contextlib.contextmanager
+    def span(self, name: str, trace_id: Optional[int] = None,
+             node_id: Optional[int] = None, **attrs) -> Iterator[Span]:
+        """Record the enclosed work as one span, child of the innermost open
+        span, and annotate it for the profiler as ``flowkv.<name>``.
+
+        ``trace_id`` and ``node_id`` default to the parent's (-1 and None at
+        the top). The span is recorded when it opens, so ``spans`` lists
+        parents before their children; its wall end is stamped on exit,
+        also when the work raises. Yields the span, whose cycle stamps and
+        attrs the caller may fill in.
+        """
+        import jax
+
+        parent = self._open[-1] if self._open else None
+        if trace_id is None:
+            trace_id = parent.trace_id if parent is not None else -1
+        if node_id is None and parent is not None:
+            node_id = parent.node_id
+        span = self.emit(trace_id, name, node_id=node_id, attrs=attrs)
+        self._open.append(span)
+        try:
+            with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
+                span.start_wall_s = self.wall()
+                yield span
+        finally:
+            span.end_wall_s = self.wall()
+            self._open.pop()
+
+    # -- compile attribution ---------------------------------------------------------
+    def _on_event_duration(self, event: str, duration: float, **_) -> None:
+        if event == COMPILE_EVENT:
+            self.compiles.append(CompileCharge(
+                self.wall(), duration,
+                self._open[-1].name if self._open else None))
+
+    def listen(self) -> None:
+        """Start charging compile requests to open spans (idempotent)."""
+        if not self._listening:
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_event_duration)
+            self._listening = True
+
+    def unlisten(self) -> None:
+        if self._listening:
+            import jax
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_event_duration)
+            self._listening = False
+
+    def compile_by_span(self) -> Dict[Optional[str], Dict[str, float]]:
+        """Compile requests and their seconds, by the span charged."""
+        out: Dict[Optional[str], Dict[str, float]] = {}
+        for c in self.compiles:
+            entry = out.setdefault(c.span, {"count": 0, "seconds": 0.0})
+            entry["count"] += 1
+            entry["seconds"] += c.seconds
+        return out
 
     # -- queries (post-run analysis; not hot-path) -----------------------------
     def for_trace(self, trace_id: int) -> List[Span]:
@@ -137,8 +263,12 @@ class SpanRecorder:
     def by_name(self, name: str) -> List[Span]:
         return [s for s in self.spans if s.name == name]
 
+    def children(self, span: Span) -> List[Span]:
+        return [s for s in self.spans if s.parent_id == span.span_id]
+
     def clear(self) -> None:
         self.spans.clear()
+        self.compiles.clear()
 
 
 @dataclasses.dataclass
@@ -227,19 +357,35 @@ def read_trace(path: Union[str, pathlib.Path]) -> Trace:
     return trace
 
 
+def _producers(target) -> List[Any]:
+    controller = getattr(target, "controller", None)
+    return ([target] + ([controller] if controller is not None else [])
+            + list(getattr(target, "engines", {}).values()))
+
+
 def attach_tracer(target, recorder: Optional[SpanRecorder] = None
                   ) -> SpanRecorder:
     """Instrument a live ``PDCluster`` or ``ClusterSim`` (and its controller
     and engines) with a span recorder; returns the recorder.
 
     Producers read ``self.tracer`` at emission time, so attaching after
-    construction instruments everything from the next event on.
+    construction instruments everything from the next event on. The
+    recorder charges compile requests to its open spans until
+    :func:`detach_tracer`.
     """
     recorder = recorder or SpanRecorder()
-    target.tracer = recorder
-    controller = getattr(target, "controller", None)
-    if controller is not None:
-        controller.tracer = recorder
-    for engine in getattr(target, "engines", {}).values():
-        engine.tracer = recorder
+    for producer in _producers(target):
+        producer.tracer = recorder
+    recorder.listen()
+    return recorder
+
+
+def detach_tracer(target) -> Optional[SpanRecorder]:
+    """Undo :func:`attach_tracer`: producers stop emitting and the recorder
+    stops listening for compiles. Returns the recorder (None if none)."""
+    recorder = getattr(target, "tracer", None)
+    for producer in _producers(target):
+        producer.tracer = None
+    if recorder is not None:
+        recorder.unlisten()
     return recorder

@@ -23,10 +23,12 @@ from repro.core.scheduler.global_controller import (AdmissionDecision,
                                                     GlobalController, ModelCost,
                                                     NodeHandle)
 from repro.core.transfer import (ShardedTransferEngine, TransferEngine,
-                                 backend_for_engine, land_sharded_plan,
-                                 pool_transfer_engine, verify_pool_transfer)
+                                 _pools_of, backend_for_engine,
+                                 land_sharded_plan, pool_transfer_engine,
+                                 verify_pool_transfer)
 from repro.faults import as_injector
 from repro.models.common import ModelConfig
+from repro.obs.tracing import NO_SPAN
 from repro.serving.engine import NodeEngine
 from repro.serving.host_tier import TierManager
 from repro.serving.request import Request, RequestState
@@ -54,6 +56,17 @@ class TransferRecord:
     # node). Latency aggregates only count "ok" records.
     status: str = "ok"
     retries: int = 0            # failed attempts absorbed by THIS transfer
+
+
+def _moved(plan) -> dict:
+    """Span attrs of what a transfer plan moves (none for a state move)."""
+    if plan is None:
+        return {}
+    return {"pages": len(plan.to_descriptors()), "bytes": plan.total_bytes}
+
+
+def _pool_bytes(*engines: NodeEngine) -> int:
+    return sum(p.nbytes for e in engines for p in _pools_of(e.kv))
 
 
 class PDCluster:
@@ -223,8 +236,18 @@ class PDCluster:
 
         The backend (paged vs state vs anything third-party) is resolved
         from the source engine — this method never branches on the cache
-        transport itself.
+        transport itself. Traced, the move is one ``transfer`` span holding
+        ``transfer.plan``, ``transfer.execute`` and ``transfer.verify``; an
+        aborted move's span carries its ``status``.
         """
+        tracer = self.tracer
+        if tracer is None:
+            self._move(req, None)
+            return
+        with tracer.span("transfer", req.request_id, req.prefill_node) as span:
+            self._move(req, span)
+
+    def _move(self, req: Request, span) -> None:
         src = self.engines[req.prefill_node]
         # Failover re-target: the decode node chosen at routing time may
         # have died while the request prefilled. Re-pick BEFORE planning so
@@ -246,20 +269,21 @@ class PDCluster:
             dst.scheduler.enqueue_decode(req)
             self._rehome_prefix(req, src.node_id,
                                 src.scheduler.bm.get(req.request_id))
-            if self.tracer is not None:
-                self.tracer.emit(
-                    req.request_id, "transfer",
-                    start_cycle=req.transfer_start, end_cycle=req.transfer_end,
-                    start_wall_s=req.transfer_start_wall,
-                    end_wall_s=req.transfer_end_wall, node_id=src.node_id,
-                    attrs={"schedule": "local", "calls": 0, "dispatches": 0,
-                           "bytes": 0, "est_latency_s": 0.0})
+            if span is not None:
+                span.start_cycle = span.end_cycle = req.transfer_start
+                span.attrs.update(schedule="local", calls=0, dispatches=0,
+                                  bytes=0)
             return
         profile = select_route(
             self.controller.nodes[src.node_id].host_id ==
             self.controller.nodes[dst.node_id].host_id, self.target)
         backend = backend_for_engine(src, self.transfer_schedule)
-        job = backend.plan(req, src, dst)
+        with (self.tracer.span("transfer.plan") if span is not None
+              else NO_SPAN) as plan_span:
+            job = backend.plan(req, src, dst)
+            if plan_span is not None:
+                plan_span.attrs.update(bytes=job.num_bytes,
+                                       calls=job.num_calls)
         hidden = 0.0
         windows = 1
         retries_before = req.transfer_retries
@@ -269,6 +293,8 @@ class PDCluster:
                 req, src, dst, job, profile)
             windows = -(-job.plan.num_layers // self.layer_window)
             if outcome != "ok":
+                if span is not None:
+                    span.attrs["status"] = outcome
                 self._abort_transfer(req, src, dst, job, outcome,
                                      req.transfer_retries - retries_before)
                 return
@@ -277,6 +303,8 @@ class PDCluster:
                 req, src, dst, lambda: backend.execute(job, src, dst),
                 job.plan)
             if penalty is None:
+                if span is not None:
+                    span.attrs["status"] = "exhausted"
                 self._abort_transfer(req, src, dst, job, "exhausted",
                                      req.transfer_retries - retries_before)
                 return
@@ -291,19 +319,15 @@ class PDCluster:
         req.transfer_end_wall = time.monotonic()
         req.transfer_calls = job.num_calls
         req.transfer_dispatches = job.num_dispatches
-        if self.tracer is not None:
-            self.tracer.emit(
-                req.request_id, "transfer",
-                start_cycle=req.transfer_start, end_cycle=req.transfer_end,
-                start_wall_s=req.transfer_start_wall,
-                end_wall_s=req.transfer_end_wall, node_id=src.node_id,
-                attrs={"schedule": job.schedule, "calls": job.num_calls,
-                       "dispatches": job.num_dispatches,
-                       "bytes": job.num_bytes, "est_latency_s": latency,
-                       "hidden_s": hidden, "windows": windows,
-                       "dst_node": dst.node_id,
-                       "src_tp": src.tp_degree, "dst_tp": dst.tp_degree,
-                       "retries": req.transfer_retries - retries_before})
+        if span is not None:
+            span.start_cycle, span.end_cycle = (req.transfer_start,
+                                                req.transfer_end)
+            span.attrs.update(
+                schedule=job.schedule, calls=job.num_calls,
+                dispatches=job.num_dispatches, bytes=job.num_bytes,
+                hidden_s=hidden, windows=windows, dst_node=dst.node_id,
+                src_tp=src.tp_degree, dst_tp=dst.tp_degree,
+                retries=req.transfer_retries - retries_before)
         # The prompt's KV now lives on the DECODE node; sending_done below
         # frees the prefill-side blocks (and invalidates their entries), so
         # the index entry is re-homed to where the KV actually is.
@@ -345,6 +369,7 @@ class PDCluster:
         penalty = 0.0
         verifiable = (plan is not None and src.kv is not None
                       and dst.kv is not None)
+        tracer = self.tracer
         for attempt in range(self.transfer_max_retries + 1):
             fault = self.faults.transfer_attempt(self.clock) \
                 if self.faults is not None else None
@@ -352,11 +377,19 @@ class PDCluster:
             if fault is not None and not corrupting:
                 ok = False          # dropped on the wire: nothing reached dst
             else:
-                execute()
+                with (tracer.span("transfer.execute", **_moved(plan))
+                      if tracer is not None else NO_SPAN):
+                    execute()
                 if corrupting:
                     self._corrupt_dst(dst, plan)
-                ok = verify_pool_transfer(plan, src.kv, dst.kv) \
-                    if verifiable else True
+                if verifiable:
+                    # the checksum reads both pools back to the host
+                    with (tracer.span("transfer.verify",
+                                      host_bytes=_pool_bytes(src, dst))
+                          if tracer is not None else NO_SPAN):
+                        ok = verify_pool_transfer(plan, src.kv, dst.kv)
+                else:
+                    ok = True
             if ok:
                 return penalty
             req.transfer_retries += 1
@@ -651,6 +684,13 @@ class PDCluster:
     def step(self) -> None:
         """One cluster cycle: faults due + controller + every node + transfers."""
         self.clock += 1.0
+        if self.tracer is None:
+            self._cycle()
+            return
+        with self.tracer.span("cluster.step", cycle=self.clock):
+            self._cycle()
+
+    def _cycle(self) -> None:
         if self.faults is not None:
             for spec in self.faults.due(self.clock):
                 if spec.node_id not in self._dead:

@@ -42,6 +42,7 @@ from repro.core.scheduler.hybrid_scheduler import HybridScheduler, ScheduleDecis
 from repro.distributed import tp as tp_mod
 from repro.models.api import Model, get_model
 from repro.models.common import ModelConfig
+from repro.obs.tracing import NO_SPAN
 from repro.serving.kv_cache import PagedKVCache, ShardedKVCache, spec_for_model
 from repro.serving.request import Request, RequestState
 
@@ -178,6 +179,7 @@ class NodeEngine:
         self.decode_steps = 0          # decode cycles executed
         self.decode_dispatches = 0     # device dispatches those cycles issued
         self._decode_cache_keys: Set[Tuple[int, int]] = set()   # jit buckets seen
+        self._prefill_shapes: Set[Tuple[int, int]] = set()      # (offset, chunk) seen
         # -- prefix-reuse data plane ---------------------------------------------------
         # A prefix-cache hit only skips work on the paged path with a
         # suffix-capable model (windowed attention and state families
@@ -198,8 +200,9 @@ class NodeEngine:
         # Optional repro.obs.tracing.SpanRecorder; read at emission time, so
         # attach_tracer() can instrument a live engine. The engine emits the
         # "prefill" span (it is where prefill runs and where wall-clock
-        # stamps originate); queue/transfer/decode spans come from the
-        # cluster, admission spans from the controller.
+        # stamps originate) and the step spans of a prefill chunk and a
+        # decode step; queue/transfer/decode spans come from the cluster,
+        # admission spans from the controller.
         self.tracer = None
 
     @property
@@ -242,61 +245,18 @@ class NodeEngine:
             if final:
                 req.last_prefill_chunk_tokens = chunk
             cached = req.num_cached_prefix_tokens if self.supports_prefix_reuse else 0
-            chunk_wall = time.monotonic()
-            if offset > 0:
-                # Suffix chunk: resident prefix = cached-prefix blocks
-                # (shared ref-counted or landed by a remote fetch) plus any
-                # previously-executed chunks' pages. Forward ONLY
-                # prompt[offset:offset+chunk], attending over the resident
-                # K/V, and write only this chunk's pages — a prefix-cache
-                # hit skips real compute, a chunk continuation resumes it.
-                k_pre, v_pre = self.kv.gather_prefix(req.request_id, offset)
-                tokens = jnp.asarray(
-                    [req.prompt_tokens[offset:offset + chunk]], jnp.int32)
-                if self.tp_degree > 1:
-                    logits, cache = tp_mod.sharded_prefill_suffix(
-                        self.shard_params, self.cfg, tokens,
-                        k_pre[:, None], v_pre[:, None])
-                else:
-                    logits, cache = self.model.prefill_suffix(
-                        self.params, {"tokens": tokens},
-                        k_pre[:, None], v_pre[:, None])
-                self.kv.write_prefill(req.request_id, cache["k"][:, 0],
-                                      cache["v"][:, 0], chunk, start=offset)
-                if offset == cached and cached > 0:
-                    # first executed chunk of a prefix-hit request
-                    self.prefix_hits += 1
-                    self.prefix_tokens_reused += cached
-            else:
-                tokens = jnp.asarray([req.prompt_tokens[:chunk]], jnp.int32)
-                if self.tp_degree > 1:
-                    logits, cache = tp_mod.sharded_prefill(
-                        self.shard_params, self.cfg, tokens)
-                else:
-                    logits, cache = self.model.prefill(self.params,
-                                                       {"tokens": tokens})
-                if self.paged:
-                    self.kv.write_prefill(req.request_id, cache["k"][:, 0],
-                                          cache["v"][:, 0], chunk)
-                else:
-                    self.states[req.request_id] = jax.tree.map(lambda x: x, cache)
-            if final and not req.output_tokens:
-                # only the last chunk's last position is the real next-token
-                # distribution; intermediate chunks' logits are discarded.
-                # A RECOVERY prefill (reset_for_retry folded emitted tokens
-                # into the prompt) re-predicts a token the client already
-                # has — output_tokens is non-empty, so the duplicate append
-                # is skipped and decode resumes from the kept token.
-                req.output_tokens.append(int(jnp.argmax(logits[0])))
+            new_shape = (offset, chunk) not in self._prefill_shapes
+            self._prefill_shapes.add((offset, chunk))
+            tracer = self.tracer
+            with (tracer.span("prefill_chunk", req.request_id, self.node_id,
+                              offset=offset, tokens=chunk,
+                              prompt_len=req.prompt_len, final=final,
+                              new_shape=new_shape)
+                  if tracer is not None else NO_SPAN) as span:
+                if span is not None:
+                    span.start_cycle = span.end_cycle = now
+                self._prefill_chunk(req, offset, chunk, final, cached)
             self.prefill_tokens_computed += chunk
-            if self.tracer is not None:
-                self.tracer.emit(
-                    req.request_id, "prefill_chunk",
-                    start_cycle=now, end_cycle=now,
-                    start_wall_s=chunk_wall, end_wall_s=time.monotonic(),
-                    node_id=self.node_id,
-                    attrs={"offset": offset, "tokens": chunk,
-                           "prompt_len": req.prompt_len, "final": final})
             # report ONLY the tokens this cycle actually forwarded:
             # prefill_progressed seeds progress at num_cached_prefix_tokens,
             # so reporting prompt_len here double-counted the hit and let the
@@ -320,6 +280,69 @@ class NodeEngine:
         self.scheduler.last_compute_util = 1.0 if decision.prefill_batch else 0.0
         return done
 
+    def _prefill_chunk(self, req: Request, offset: int, chunk: int,
+                       final: bool, cached: int) -> None:
+        """Forward ``prompt[offset:offset+chunk]`` and write its pages; the
+        final chunk of a fresh prompt also emits the first output token."""
+        tracer = self.tracer
+        if offset > 0:
+            # Suffix chunk: resident prefix = cached-prefix blocks
+            # (shared ref-counted or landed by a remote fetch) plus any
+            # previously-executed chunks' pages. Forward ONLY
+            # prompt[offset:offset+chunk], attending over the resident
+            # K/V, and write only this chunk's pages — a prefix-cache
+            # hit skips real compute, a chunk continuation resumes it.
+            with (tracer.span("prefill.gather_prefix")
+                  if tracer is not None else NO_SPAN):
+                k_pre, v_pre = self.kv.gather_prefix(req.request_id, offset)
+            with (tracer.span("prefill.forward")
+                  if tracer is not None else NO_SPAN):
+                tokens = jnp.asarray(
+                    [req.prompt_tokens[offset:offset + chunk]], jnp.int32)
+                if self.tp_degree > 1:
+                    logits, cache = tp_mod.sharded_prefill_suffix(
+                        self.shard_params, self.cfg, tokens,
+                        k_pre[:, None], v_pre[:, None])
+                else:
+                    logits, cache = self.model.prefill_suffix(
+                        self.params, {"tokens": tokens},
+                        k_pre[:, None], v_pre[:, None])
+            with (tracer.span("prefill.write")
+                  if tracer is not None else NO_SPAN):
+                self.kv.write_prefill(req.request_id, cache["k"][:, 0],
+                                      cache["v"][:, 0], chunk, start=offset)
+            if offset == cached and cached > 0:
+                # first executed chunk of a prefix-hit request
+                self.prefix_hits += 1
+                self.prefix_tokens_reused += cached
+        else:
+            with (tracer.span("prefill.forward")
+                  if tracer is not None else NO_SPAN):
+                tokens = jnp.asarray([req.prompt_tokens[:chunk]], jnp.int32)
+                if self.tp_degree > 1:
+                    logits, cache = tp_mod.sharded_prefill(
+                        self.shard_params, self.cfg, tokens)
+                else:
+                    logits, cache = self.model.prefill(self.params,
+                                                       {"tokens": tokens})
+            with (tracer.span("prefill.write")
+                  if tracer is not None else NO_SPAN):
+                if self.paged:
+                    self.kv.write_prefill(req.request_id, cache["k"][:, 0],
+                                          cache["v"][:, 0], chunk)
+                else:
+                    self.states[req.request_id] = jax.tree.map(lambda x: x, cache)
+        if final and not req.output_tokens:
+            # only the last chunk's last position is the real next-token
+            # distribution; intermediate chunks' logits are discarded.
+            # A RECOVERY prefill (reset_for_retry folded emitted tokens
+            # into the prompt) re-predicts a token the client already
+            # has — output_tokens is non-empty, so the duplicate append
+            # is skipped and decode resumes from the kept token.
+            with (tracer.span("prefill.sample")
+                  if tracer is not None else NO_SPAN):
+                req.output_tokens.append(int(jnp.argmax(logits[0])))
+
     # -- decode --------------------------------------------------------------------
     def run_decode(self, decision: ScheduleDecision) -> List[Request]:
         """One decode step for the running batch; returns finished requests."""
@@ -327,10 +350,13 @@ class NodeEngine:
         if not batch:
             return []
         finished: List[Request] = []
-        if self.paged:
-            decoded = self._decode_paged(batch)
-        else:
-            decoded = self._decode_state(batch)
+        tracer = self.tracer
+        with (tracer.span("decode.step", node_id=self.node_id, batch=len(batch))
+              if tracer is not None else NO_SPAN):
+            if self.paged:
+                decoded = self._decode_paged(batch)
+            else:
+                decoded = self._decode_state(batch)
         for req in batch:
             last = req.output_tokens[-1]
             eos = req.sampling.eos_token_id
@@ -362,21 +388,26 @@ class NodeEngine:
         instead of aiming at block 0.
         """
         b = len(batch)
-        # KV cached so far = prompt + all outputs except the newest token,
-        # whose KV is written by THIS step at position total-1.
-        lens = [r.total_len - 1 for r in batch]
-        toks = [r.output_tokens[-1] for r in batch]
-        rids = [r.request_id for r in batch]
-        tables = self.kv.export_block_tables(rids)
-        bp = _next_pow2(b)
-        wp = _next_pow2(tables.shape[1])
-        bt = np.zeros((bp, wp), np.int32)
-        bt[:b, :tables.shape[1]] = tables
-        bt[b:] = bt[0]
-        tok_arr = np.full((bp,), toks[0], np.int32)
-        tok_arr[:b] = toks
-        len_arr = np.full((bp,), lens[0], np.int32)
-        len_arr[:b] = lens
+        tracer = self.tracer
+        with (tracer.span("decode.prepare") if tracer is not None else NO_SPAN):
+            # KV cached so far = prompt + all outputs except the newest
+            # token, whose KV is written by THIS step at position total-1.
+            lens = [r.total_len - 1 for r in batch]
+            toks = [r.output_tokens[-1] for r in batch]
+            rids = [r.request_id for r in batch]
+            tables = self.kv.export_block_tables(rids)
+            bp = _next_pow2(b)
+            wp = _next_pow2(tables.shape[1])
+            bt = np.zeros((bp, wp), np.int32)
+            bt[:b, :tables.shape[1]] = tables
+            bt[b:] = bt[0]
+            tok_arr = np.full((bp,), toks[0], np.int32)
+            tok_arr[:b] = toks
+            len_arr = np.full((bp,), lens[0], np.int32)
+            len_arr[:b] = lens
+            tok_arr, bt, len_arr = (jnp.asarray(tok_arr), jnp.asarray(bt),
+                                    jnp.asarray(len_arr))
+        new_bucket = (bp, wp) not in self._decode_cache_keys
         self._decode_cache_keys.add((bp, wp))
         # decode_dispatches counts host-issued device computations, by
         # construction: this branch launches exactly ONE (the jitted step —
@@ -384,21 +415,23 @@ class NodeEngine:
         # below is a host read, not a launch). Anyone adding a second device
         # call to this path must bump the increment or the O(1) claim that
         # benchmarks/decode_throughput.py --check enforces becomes a lie.
-        if self.tp_degree > 1:
-            logits, new_pools = self._paged_step(
-                self.shard_params, jnp.asarray(tok_arr),
-                tuple(s.pool for s in self.kv.shards),
-                jnp.asarray(bt), jnp.asarray(len_arr))
-            for shard, pool in zip(self.kv.shards, new_pools):
-                shard.pool = pool
-        else:
-            logits, self.kv.pool = self._paged_step(
-                self.params, jnp.asarray(tok_arr), self.kv.pool,
-                jnp.asarray(bt), jnp.asarray(len_arr))
+        with (tracer.span("decode.dispatch", bucket=[bp, wp],
+                          new_bucket=new_bucket)
+              if tracer is not None else NO_SPAN):
+            if self.tp_degree > 1:
+                logits, new_pools = self._paged_step(
+                    self.shard_params, tok_arr,
+                    tuple(s.pool for s in self.kv.shards), bt, len_arr)
+                for shard, pool in zip(self.kv.shards, new_pools):
+                    shard.pool = pool
+            else:
+                logits, self.kv.pool = self._paged_step(
+                    self.params, tok_arr, self.kv.pool, bt, len_arr)
         self.kv.num_pool_dispatches += 1
         self.decode_steps += 1
         self.decode_dispatches += 1
-        nxt = np.argmax(np.asarray(logits, np.float32)[:b], axis=-1)
+        with (tracer.span("decode.readback") if tracer is not None else NO_SPAN):
+            nxt = np.argmax(np.asarray(logits, np.float32)[:b], axis=-1)
         for i, r in enumerate(batch):
             r.output_tokens.append(int(nxt[i]))
             r.decode_steps += 1

@@ -149,7 +149,8 @@ class PagedKVCache:
         blocks holding them (the shared prefix of a cache hit), so fresh
         suffix blocks full of garbage are never touched."""
         nb = self.spec.blocks_for_tokens(length)
-        return self.gather_dense(request_id, length, num_blocks=nb)
+        with jax.named_scope("prefix_gather"):
+            return self.gather_dense(request_id, length, num_blocks=nb)
 
     def gather_dense(self, request_id: int, max_len: int,
                      num_blocks: Optional[int] = None
@@ -279,7 +280,8 @@ class ShardedKVCache:
     def gather_prefix(self, request_id: int, length: int
                       ) -> Tuple[jax.Array, jax.Array]:
         nb = self.spec.blocks_for_tokens(length)
-        return self.gather_dense(request_id, length, num_blocks=nb)
+        with jax.named_scope("prefix_gather"):
+            return self.gather_dense(request_id, length, num_blocks=nb)
 
     def gather_dense(self, request_id: int, max_len: int,
                      num_blocks: Optional[int] = None

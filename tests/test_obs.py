@@ -12,8 +12,9 @@ from repro.obs import attach_tracer, read_trace, write_trace
 from repro.obs.calibrate import fit_compute, fit_hardware, fit_transport
 from repro.obs.history import AREAS, check, check_metrics, load, record
 from repro.obs.replay import capture, per_request_stats, replay
-from repro.obs.tracing import (SPAN_NAMES, TRACE_SCHEMA_VERSION, Span,
-                               SpanRecorder, request_record)
+from repro.obs.tracing import (COMPILE_EVENT, SPAN_NAMES, STEP_SPAN_NAMES,
+                               TRACE_SCHEMA_VERSION, Span, SpanRecorder,
+                               detach_tracer, request_record)
 from repro.sim.cluster_sim import ClusterSim
 from repro.sim.hardware import A100
 from repro.sim.workload import SIMULATED, generate
@@ -97,6 +98,228 @@ def test_chunk_and_layer_window_span_kinds_roundtrip(tmp_path):
              node_id=0, attrs={"layer_lo": 0, "layer_hi": 8, "hidden": True})
     path = write_trace(tmp_path / "t2.jsonl", rec.spans)
     assert read_trace(path).spans == rec.spans
+
+
+@pytest.mark.parametrize("schema", [2, 3, 4, 5])
+def test_traces_before_span_ids_still_read(tmp_path, schema):
+    p = tmp_path / f"v{schema}.jsonl"
+    p.write_text('{"kind": "header", "schema": %d}\n'
+                 '{"kind": "span", "trace_id": 1, "name": "transfer", '
+                 '"start_wall_s": 0.0, "end_wall_s": 1.0}\n' % schema)
+    trace = read_trace(p)
+    assert trace.schema == schema
+    assert trace.spans[0].span_id is None and trace.spans[0].parent_id is None
+
+
+def test_nested_spans_set_parent_ids_and_roundtrip(tmp_path):
+    rec = SpanRecorder()
+    with rec.span("cluster.step", cycle=1.0) as step:
+        with rec.span("transfer", trace_id=7, node_id=0) as xfer:
+            with rec.span("transfer.verify", host_bytes=64) as verify:
+                pass
+            emitted = rec.emit(7, "transfer_retry")
+        with rec.span("decode.step", node_id=1) as decode:
+            pass
+    assert step.parent_id is None
+    assert xfer.parent_id == step.span_id
+    assert verify.parent_id == xfer.span_id
+    assert emitted.parent_id == xfer.span_id
+    assert decode.parent_id == step.span_id
+    # trace and node ids default to the parent's (-1 at the top)
+    assert (step.trace_id, verify.trace_id, decode.trace_id) == (-1, 7, -1)
+    assert (verify.node_id, decode.node_id) == (0, 1)
+    assert len({s.span_id for s in rec.spans}) == len(rec.spans) == 5
+    assert [s.name for s in rec.children(step)] == ["transfer", "decode.step"]
+    assert step.start_wall_s <= xfer.start_wall_s <= verify.start_wall_s \
+        <= verify.end_wall_s <= xfer.end_wall_s <= step.end_wall_s
+    path = write_trace(tmp_path / "v6.jsonl", rec.spans)
+    trace = read_trace(path)
+    assert trace.schema == TRACE_SCHEMA_VERSION == 6
+    assert trace.spans == rec.spans
+
+
+def test_span_closes_when_the_work_raises():
+    rec = SpanRecorder()
+    with pytest.raises(RuntimeError):
+        with rec.span("decode.step"):
+            raise RuntimeError("device lost")
+    assert rec.spans[0].end_wall_s is not None
+    with rec.span("cluster.step") as step:
+        pass
+    assert step.parent_id is None          # the failed span was popped
+
+
+def test_compiles_charged_to_innermost_open_span():
+    import jax
+    rec = SpanRecorder()
+    rec.listen()
+    try:
+        jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.5)
+        with rec.span("prefill_chunk"):
+            with rec.span("prefill.forward"):
+                jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 1.0)
+                jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 2.0)
+                jax.monitoring.record_event_duration_secs("/other", 9.0)
+    finally:
+        rec.unlisten()
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 4.0)
+    assert rec.compile_by_span() == {
+        None: {"count": 1, "seconds": 0.5},
+        "prefill.forward": {"count": 2, "seconds": 3.0}}
+
+
+# -- step spans of the real cluster ----------------------------------------------------
+def _served(cfg, params, trace, lengths=(40, 70), new_tokens=4):
+    """Serve prompts of ``lengths`` on a 1P+1D cluster; returns the tokens
+    and the recorder (None untraced)."""
+    from repro.serving.cluster import PDCluster
+    from repro.serving.request import Request, SamplingParams
+    cluster = PDCluster(cfg, params, num_prefill=1, num_decode=1,
+                        num_blocks=64, prefill_chunk_tokens=32)
+    rec = attach_tracer(cluster) if trace else None
+    rng = np.random.RandomState(0)
+    reqs = [Request(prompt_tokens=rng.randint(0, cfg.vocab_size, n).tolist(),
+                    sampling=SamplingParams(max_new_tokens=new_tokens))
+            for n in lengths]
+    cluster.run(reqs, max_cycles=60)
+    if trace:
+        assert detach_tracer(cluster) is rec
+        assert cluster.tracer is None
+    assert all(r.num_output == new_tokens for r in reqs)
+    return [list(r.output_tokens) for r in reqs], rec
+
+
+@pytest.fixture(scope="module")
+def small_qwen():
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.models.api import get_model
+    cfg = get_smoke_config("qwen3-1.7b")
+    return cfg, get_model(cfg).init(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def served_both(small_qwen, tmp_path_factory):
+    """The same prompts served untraced, then traced under the profiler;
+    counts the profiler annotations and compile listeners each run made."""
+    import glob
+    import jax
+    cfg, params = small_qwen
+    made = {"annotations": 0, "listeners": 0}
+    real_annotation = jax.profiler.TraceAnnotation
+    real_register = jax.monitoring.register_event_duration_secs_listener
+
+    def annotation(name, **kw):
+        made["annotations"] += name.startswith("flowkv.")
+        return real_annotation(name, **kw)
+
+    def register(fn):
+        made["listeners"] += 1
+        return real_register(fn)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax.profiler, "TraceAnnotation", annotation)
+    mp.setattr(jax.monitoring, "register_event_duration_secs_listener", register)
+    try:
+        untraced, none = _served(cfg, params, trace=False)
+        untraced_made = dict(made)
+        log_dir = str(tmp_path_factory.mktemp("profile"))
+        jax.profiler.start_trace(log_dir)
+        try:
+            traced, rec = _served(cfg, params, trace=True)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        mp.undo()
+    path = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)[-1]
+    return {"untraced": untraced, "untraced_made": untraced_made,
+            "traced_made": made, "traced": traced, "rec": rec, "none": none,
+            "profile": jax.profiler.ProfileData.from_file(path)}
+
+
+def test_untraced_cluster_creates_no_spans_or_listeners(served_both):
+    assert served_both["none"] is None
+    assert served_both["untraced_made"] == {"annotations": 0, "listeners": 0}
+    # the same count sees the traced run's annotations and its listener
+    spanned = STEP_SPAN_NAMES + ("prefill_chunk", "transfer")
+    assert served_both["traced_made"]["annotations"] == sum(
+        s.name in spanned for s in served_both["rec"].spans)
+    assert served_both["traced_made"]["listeners"] == 1
+
+
+def test_tracing_does_not_change_served_tokens(served_both):
+    assert served_both["traced"] == served_both["untraced"]
+
+
+def test_step_spans_nest_on_the_cluster(served_both):
+    rec = served_both["rec"]
+    # a 70-token prompt in 32-token chunks takes suffix chunks, so every
+    # step span kind of the paged path appears
+    assert set(STEP_SPAN_NAMES) <= {s.name for s in rec.spans}
+    by_id = {s.span_id: s for s in rec.spans}
+    for child, want in (("decode.prepare", "decode.step"),
+                        ("decode.dispatch", "decode.step"),
+                        ("decode.readback", "decode.step"),
+                        ("decode.step", "cluster.step"),
+                        ("prefill.gather_prefix", "prefill_chunk"),
+                        ("prefill.forward", "prefill_chunk"),
+                        ("prefill.write", "prefill_chunk"),
+                        ("prefill.sample", "prefill_chunk"),
+                        ("prefill_chunk", "cluster.step"),
+                        ("transfer.plan", "transfer"),
+                        ("transfer.execute", "transfer"),
+                        ("transfer.verify", "transfer"),
+                        ("transfer", "cluster.step")):
+        assert all(by_id[s.parent_id].name == want
+                   for s in rec.by_name(child)), child
+    for xfer in rec.by_name("transfer"):
+        kids = rec.children(xfer)
+        assert [k.name for k in kids] == ["transfer.plan", "transfer.execute",
+                                          "transfer.verify"]
+        assert xfer.trace_id >= 0
+        assert all(k.trace_id == xfer.trace_id for k in kids)
+        assert kids[1].attrs["bytes"] == xfer.attrs["bytes"] > 0
+        assert kids[1].attrs["pages"] > 0
+        assert kids[2].attrs["host_bytes"] > xfer.attrs["bytes"]
+        assert "est_latency_s" not in xfer.attrs
+    for step in rec.by_name("decode.step"):
+        dispatch = [k for k in rec.children(step) if k.name == "decode.dispatch"]
+        assert dispatch[0].attrs["bucket"][0] >= step.attrs["batch"]
+    assert any(c.attrs["new_shape"] for c in rec.by_name("prefill_chunk"))
+
+
+def _host_events(profile, name):
+    return [(line.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+            for plane in profile.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events if ev.name == name]
+
+
+@pytest.mark.parametrize("outer,inner", [
+    ("flowkv.decode.step", "flowkv.decode.readback"),
+    ("flowkv.transfer", "flowkv.transfer.verify"),
+    ("flowkv.cluster.step", "flowkv.prefill_chunk"),
+])
+def test_step_spans_land_on_the_profiler_host_plane(served_both, outer, inner):
+    profile = served_both["profile"]
+    outers, inners = _host_events(profile, outer), _host_events(profile, inner)
+    assert len(outers) == len(served_both["rec"].by_name(outer[len("flowkv."):]))
+    assert len(inners) == len(served_both["rec"].by_name(inner[len("flowkv."):]))
+    for line, a, b in inners:
+        assert any(ol == line and oa <= a and b <= ob for ol, oa, ob in outers)
+
+
+def test_new_prefill_shape_compiles_are_charged_to_prefill(small_qwen):
+    cfg, params = small_qwen
+    # 32 + 11 tokens: the (32, 11) suffix chunk is a shape no other test
+    # of this module prefills
+    _, rec = _served(cfg, params, trace=True, lengths=(43,), new_tokens=2)
+    fresh = [c for c in rec.by_name("prefill_chunk")
+             if c.attrs["offset"] == 32 and c.attrs["new_shape"]]
+    assert len(fresh) == 1
+    lo, hi = fresh[0].start_wall_s, fresh[0].end_wall_s
+    inside = [c for c in rec.compiles if lo <= c.end_wall_s <= hi]
+    assert inside and all(c.span.startswith("prefill") for c in inside)
+    assert sum(c.seconds for c in inside) > 0
 
 
 # -- sim tracing ---------------------------------------------------------------------

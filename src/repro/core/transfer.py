@@ -67,6 +67,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, List, Literal, Optional, Sequence, Tuple
 
 import jax
@@ -628,21 +629,62 @@ class ShardedTransferEngine:
 
 
 # ---------------------------------------------------------------------------
-# Payload integrity: per-plan checksums over the pages a plan moves
+# Payload integrity: a bit-exact device compare over the pages a plan moved
 # ---------------------------------------------------------------------------
-def payload_digest(pool: jax.Array, spec: L.KVCacheSpec,
-                   page_ids: np.ndarray) -> bytes:
-    """blake2b digest of the given flat pages of a pool.
+# Bytes of one side's rows gathered per step of the compare loop: bounds the
+# check's temporaries far below a plan's moved pages (~1.9 GB a side at 16k
+# tokens of qwen3-1.7b).
+_CHECK_STEP_BYTES = 16 << 20
 
-    The pool is viewed as ``(num_pages, spec.payload)`` — the same flat-page
-    view the fused executor gathers/scatters through — so a digest over a
-    plan's page ids covers exactly the bytes that plan moves, regardless of
-    layout (FLOWKV vs VLLM page orderings index the same view differently).
+
+def check_bucket(pages: int) -> int:
+    """Padded page count of a plan's check: the next power of two, so one
+    compiled compare serves every plan of up to that many pages."""
+    return 1 << (pages - 1).bit_length() if pages else 0
+
+
+def _pad_pages(ids: np.ndarray, bucket: int) -> np.ndarray:
+    """Pad a page-id table to ``bucket`` entries by repeating its last id.
+    A repeated pair compares equal exactly when the real last pair does."""
+    return np.concatenate([ids, np.repeat(ids[-1:], bucket - len(ids))])
+
+
+def _bits(x: jax.Array) -> jax.Array:
+    """The bit pattern of ``x``, so NaN payloads and +-0 compare exactly."""
+    return jax.lax.bitcast_convert_type(
+        x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _rows_equal(src: jax.Array, dst: jax.Array, src_idx: jax.Array,
+                dst_idx: jax.Array, step: int) -> jax.Array:
+    """Device flag: every indexed dst row equals its src row, bit for bit.
+
+    ``src_idx`` / ``dst_idx`` are ``(ndim - 1, n)`` leading indices of each
+    row in its array's native layout (row ``i`` of one pairs with row ``i``
+    of the other). Rows are gathered and compared ``step`` at a time, so the
+    check never holds all ``n`` gathered rows at once.
     """
-    import hashlib
-    flat = np.asarray(pool).reshape(-1, spec.payload)
-    return hashlib.blake2b(np.ascontiguousarray(flat[page_ids]).tobytes(),
-                           digest_size=16).digest()
+    def body(i, ok):
+        lo = i * step
+        a = src[tuple(jax.lax.dynamic_slice_in_dim(src_idx, lo, step, 1))]
+        b = dst[tuple(jax.lax.dynamic_slice_in_dim(dst_idx, lo, step, 1))]
+        return ok & jnp.all(_bits(a) == _bits(b))
+
+    return jax.lax.fori_loop(0, src_idx.shape[1] // step, body,
+                             jnp.bool_(True))
+
+
+def _rows_equal_flag(src: jax.Array, src_rows: np.ndarray, dst: jax.Array,
+                     dst_rows: np.ndarray) -> jax.Array:
+    """Launch :func:`_rows_equal` on flat row ids over each array's leading
+    axes (the last axis is the row payload); returns the device flag."""
+    rows_per_step = max(_CHECK_STEP_BYTES // (src.shape[-1] * src.itemsize), 1)
+    step = math.gcd(len(src_rows), 1 << (rows_per_step.bit_length() - 1))
+    src_idx = np.stack(np.unravel_index(src_rows, src.shape[:-1]))
+    dst_idx = np.stack(np.unravel_index(dst_rows, dst.shape[:-1]))
+    return _rows_equal(src, dst, jnp.asarray(src_idx, jnp.int32),
+                       jnp.asarray(dst_idx, jnp.int32), step)
 
 
 def verify_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
@@ -650,20 +692,21 @@ def verify_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
                     dst_pool: jax.Array) -> bool:
     """Post-dispatch integrity check: did the dst pages land bit-identical?
 
-    Digests the plan's source pages and destination pages (each through its
-    own layout's page ordering, which pairs row-for-row by construction) and
-    compares. An empty plan trivially verifies. This is the receiver-side
-    checksum a real transport would carry per message; here both pools are
-    addressable so the check is exact, not probabilistic framing.
+    Compares the plan's source pages with its destination pages on the
+    device (row ``i`` of each side's page table, each through its own
+    layout, indexed in the pool's native shape) and reads back one flag.
+    Both page tables are padded to :func:`check_bucket` of the plan's page
+    count, so plans of one bucket share one compiled compare. An empty plan
+    trivially verifies. The check is exact, not probabilistic framing.
     """
     table = plan.to_descriptors()
     if len(table) == 0:
         return True
-    src_digest = payload_digest(src_pool, src_spec,
-                                table.page_ids(src_spec, "src"))
-    dst_digest = payload_digest(dst_pool, dst_spec,
-                                table.page_ids(dst_spec, "dst"))
-    return src_digest == dst_digest
+    bucket = check_bucket(len(table))
+    flag = _rows_equal_flag(
+        src_pool, _pad_pages(table.page_ids(src_spec, "src"), bucket),
+        dst_pool, _pad_pages(table.page_ids(dst_spec, "dst"), bucket))
+    return bool(jax.device_get(flag))
 
 
 def verify_sharded_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
@@ -672,12 +715,12 @@ def verify_sharded_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
                             dst_pools: Sequence[jax.Array]) -> bool:
     """Shard-aware twin of :func:`verify_transfer`.
 
-    Digests each overlapping (src_shard, dst_shard) pair's fine
-    ``(-1, head_dim)`` rows — exactly the rows the per-pair dispatch moved —
-    and compares src vs dst. The plan must carry shard topology (see
+    Compares each overlapping (src_shard, dst_shard) pair's fine
+    ``(-1, head_dim)`` rows — exactly the rows the per-pair dispatch moved,
+    expanded from the bucket-padded page tables — on the device, and reads
+    back one flag for all pairs. The plan must carry shard topology (see
     ``TransferPlan.src_shard`` / ``dst_shard``); pools are per-shard lists.
     """
-    import hashlib
     table = plan.to_descriptors()
     if len(table) == 0:
         return True
@@ -687,22 +730,22 @@ def verify_sharded_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
     src_shard = plan.src_shard or ShardSpec(1, heads)
     dst_shard = plan.dst_shard or ShardSpec(1, heads)
     hd = src_spec.head_dim
+    bucket = check_bucket(len(table))
 
-    def digest(pool, spec, shard, shard_idx, lo, hi, side):
+    def rows(spec, shard, shard_idx, lo, hi, side):
         sspec = shard_slice_spec(spec, shard)
-        rows = fine_page_rows(table.page_ids(sspec, side), spec.block_size,
-                              sspec.num_kv_heads,
-                              lo - shard.head_range(shard_idx)[0],
-                              hi - shard.head_range(shard_idx)[0])
-        flat = np.asarray(pool).reshape(-1, hd)
-        return hashlib.blake2b(np.ascontiguousarray(flat[rows]).tobytes(),
-                               digest_size=16).digest()
+        pages = _pad_pages(table.page_ids(sspec, side), bucket)
+        base = shard.head_range(shard_idx)[0]
+        return fine_page_rows(pages, spec.block_size, sspec.num_kv_heads,
+                              lo - base, hi - base)
 
-    for s, d, lo, hi in shard_pairs(src_shard, dst_shard):
-        if (digest(src_pools[s], src_spec, src_shard, s, lo, hi, "src")
-                != digest(dst_pools[d], dst_spec, dst_shard, d, lo, hi, "dst")):
-            return False
-    return True
+    flags = [_rows_equal_flag(
+        src_pools[s].reshape(-1, hd),
+        rows(src_spec, src_shard, s, lo, hi, "src"),
+        dst_pools[d].reshape(-1, hd),
+        rows(dst_spec, dst_shard, d, lo, hi, "dst"))
+        for s, d, lo, hi in shard_pairs(src_shard, dst_shard)]
+    return bool(jax.device_get(jnp.all(jnp.stack(flags))))
 
 
 def _pools_of(kv) -> List[jax.Array]:

@@ -86,7 +86,8 @@ SPAN_NAMES = ("queue", "admission", "prefill", "prefill_chunk", "transfer",
 # (block tables, padding, host->device inputs), ``decode.dispatch`` (the
 # jitted step) and ``decode.readback`` (the logits read and argmax);
 # ``transfer`` holds ``transfer.plan``, ``transfer.execute`` (the kernel
-# dispatch) and ``transfer.verify`` (the checksum). Host spans time the host:
+# dispatch) and ``transfer.verify`` (the moved pages compared on the device,
+# one flag read back). Host spans time the host:
 # a span that ends in a host read (``decode.readback``, ``prefill.sample``,
 # ``transfer.verify``) includes the wait for the device.
 STEP_SPAN_NAMES = ("cluster.step", "prefill.gather_prefix", "prefill.forward",

@@ -23,7 +23,7 @@ from repro.core.scheduler.global_controller import (AdmissionDecision,
                                                     GlobalController, ModelCost,
                                                     NodeHandle)
 from repro.core.transfer import (ShardedTransferEngine, TransferEngine,
-                                 _pools_of, backend_for_engine,
+                                 backend_for_engine, check_bucket,
                                  land_sharded_plan, pool_transfer_engine,
                                  verify_pool_transfer)
 from repro.faults import as_injector
@@ -65,8 +65,12 @@ def _moved(plan) -> dict:
     return {"pages": len(plan.to_descriptors()), "bytes": plan.total_bytes}
 
 
-def _pool_bytes(*engines: NodeEngine) -> int:
-    return sum(p.nbytes for e in engines for p in _pools_of(e.kv))
+def _checked(plan) -> dict:
+    """Span attrs of a transfer unit's device check: the moved bytes it
+    reads (both sides), the flag it reads back, its pages and bucket."""
+    pages = len(plan.to_descriptors())
+    return {"device_bytes": 2 * plan.total_bytes, "host_bytes": int(pages > 0),
+            "pages": pages, "bucket": check_bucket(pages)}
 
 
 class PDCluster:
@@ -356,15 +360,16 @@ class PDCluster:
         """Run one transfer unit (a full plan, or one layer-window sub-plan)
         under the fault injector with post-dispatch integrity checking.
 
-        Every executed dispatch is checksum-verified (src pages vs dst pages
-        through the plan's descriptor table); a failed or corrupt attempt
-        retries with exponential backoff. Returns the latency penalty the
-        retries accrued, or None when all ``transfer_max_retries + 1``
-        attempts failed (caller degrades to recompute). An injected "fail"
-        drops the attempt before any bytes move; an injected "corrupt" lands
-        the payload then flips one destination element, so the checksum —
-        not the injector — is what catches it, and the clean retry's
-        re-execution overwrites (repairs) the damage.
+        Every executed dispatch is verified (src pages vs dst pages through
+        the plan's descriptor table, compared bit for bit on the device); a
+        failed or corrupt attempt retries with exponential backoff. Returns
+        the latency penalty the retries accrued, or None when all
+        ``transfer_max_retries + 1`` attempts failed (caller degrades to
+        recompute). An injected "fail" drops the attempt before any bytes
+        move; an injected "corrupt" lands the payload then flips one
+        destination element, so the check — not the injector — is what
+        catches it, and the clean retry's re-execution overwrites (repairs)
+        the damage.
         """
         penalty = 0.0
         verifiable = (plan is not None and src.kv is not None
@@ -383,9 +388,8 @@ class PDCluster:
                 if corrupting:
                     self._corrupt_dst(dst, plan)
                 if verifiable:
-                    # the checksum reads both pools back to the host
-                    with (tracer.span("transfer.verify",
-                                      host_bytes=_pool_bytes(src, dst))
+                    # the moved pages compared on the device; one flag read back
+                    with (tracer.span("transfer.verify", **_checked(plan))
                           if tracer is not None else NO_SPAN):
                         ok = verify_pool_transfer(plan, src.kv, dst.kv)
                 else:

@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.costmodel import TransportProfile, predicted_ttft_s
+from repro.core.transfer import check_bucket
 from repro.obs import attach_tracer, read_trace, write_trace
 from repro.obs.calibrate import fit_compute, fit_hardware, fit_transport
 from repro.obs.history import AREAS, check, check_metrics, load, record
@@ -280,7 +281,11 @@ def test_step_spans_nest_on_the_cluster(served_both):
         assert all(k.trace_id == xfer.trace_id for k in kids)
         assert kids[1].attrs["bytes"] == xfer.attrs["bytes"] > 0
         assert kids[1].attrs["pages"] > 0
-        assert kids[2].attrs["host_bytes"] > xfer.attrs["bytes"]
+        # the check reads the moved pages on both sides and one flag back
+        pages = kids[1].attrs["pages"]
+        assert kids[2].attrs == {"device_bytes": 2 * xfer.attrs["bytes"],
+                                 "host_bytes": 1, "pages": pages,
+                                 "bucket": check_bucket(pages)}
         assert "est_latency_s" not in xfer.attrs
     for step in rec.by_name("decode.step"):
         dispatch = [k for k in rec.children(step) if k.name == "decode.dispatch"]

@@ -72,7 +72,7 @@ class Cluster:
         self.cell = cell
         self.shape = spec.model_shape(cell.config)
         self.serving = cell.config["serving"]
-        cfg = spec.program_config(cell.config, cell.config_name)
+        cfg = spec.family(self.shape["family"]).program_config(cell.config, cell.config_name)
         self.weights = W.make(self.shape, seed)
         jax.block_until_ready(self.weights)
         self.client = FlowKVClient(
@@ -103,7 +103,7 @@ def served(records: List[Any]) -> List[Dict[str, List[int]]]:
 def compare(cell: spec.Cell, weights, shape, sampled: List[Dict[str, List[int]]],
             fp8: bool = False) -> Dict[str, Optional[float]]:
     """Widest gaps over the sample (served, and the control's with ``fp8``)."""
-    ref = spec.load_module("reference", shape["family"])
+    ref = spec.family(shape["family"])
     per = [check.gaps(ref, weights, shape, s["prompt"], s["served"], fp8) for s in sampled]
     out = {"served": check.widest([p["served"] for p in per])}
     if fp8:

@@ -1,15 +1,31 @@
 """What one cell is: its entry in ``BENCHMARK.json`` and the files it names.
 
-Everything that belongs to one configuration, traffic mix, metric or cell
-lives in a file of its own, found by name:
+Everything that belongs to one configuration, traffic mix, metric, cell or
+model family lives in a file of its own, found by name:
 
 * ``configs/<config>.json`` — the model's published ``config.json`` keys as
-  run, plus ``serving`` (how the program is built and sized);
+  run, plus ``serving`` (how the program is built and sized, and its
+  ``family``);
 * ``traffic/<traffic>.json`` — the parameters the one traffic generator reads;
 * ``limits/<workload>.json`` — the limits of the numbers ``correct`` compares;
 * ``metrics/<metric>.py`` — the reader of one metric (``metrics/<stem>.py``
   for a name ``<stem>.<part>`` with no file of its own);
-* ``reference/<family>.py`` — the float32 reference of one model family.
+* ``reference/<family>.py`` — everything the benchmark knows of one model
+  family, found by the configuration's ``serving.family``:
+
+  - ``shape(config) -> dict``: the sizes every other function reads, under
+    one set of names, with ``family`` among them;
+  - ``program_config(config, name)``: the program's ``ModelConfig``;
+  - ``weight_shapes(shape) -> {path: (shape, init)}``: the weight tree that
+    ``harness/weights.py`` makes, in the program's parameter layout;
+  - ``prefill_flops(shape, offset, chunk)``, ``decode_flops(shape, cached)``:
+    the model FLOPs of a prefill chunk and of one decode token;
+  - ``attention_calls(shape) -> [(kv_heads, group, head_dim, window), ...]``:
+    one entry per paged-attention call in a decode step (window 0: full);
+  - ``logits(weights, shape, tokens, first, fp8=False)``: the float32
+    reference, and with ``fp8`` the control.
+
+  A configuration of a new family enters by adding files only.
 """
 from __future__ import annotations
 
@@ -82,45 +98,20 @@ def load_module(kind: str, name: str) -> ModuleType:
     return mod
 
 
+FAMILY = ("shape", "program_config", "weight_shapes", "prefill_flops", "decode_flops",
+          "attention_calls", "logits")
+
+
+def family(name: str) -> ModuleType:
+    """``reference/<name>.py``; raises AttributeError where it lacks a
+    function of the family interface (``FAMILY``)."""
+    mod = load_module("reference", name)
+    missing = [f for f in FAMILY if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"reference/{name}.py lacks {missing}")
+    return mod
+
+
 def model_shape(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The sizes every consumer (weights, reference, FLOP counts) reads,
-    under one set of names, from a ``configs/<config>.json``."""
-    serving = config["serving"]
-    d = config["hidden_size"]
-    h = config["num_attention_heads"]
-    moe = serving["family"] == "moe"
-    return {
-        "family": serving["family"],
-        "layers": config["num_hidden_layers"],
-        "d_model": d,
-        "heads": h,
-        "kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim") or serving["head_dim"],
-        "d_ff": config["intermediate_size"],
-        "experts": config["num_local_experts"] if moe else 0,
-        "top_k": config["num_experts_per_tok"] if moe else 0,
-        "vocab": config["vocab_size"],
-        "qk_norm": bool(serving.get("qk_norm", False)),
-        "rope_theta": float(config["rope_theta"]),
-        "norm_eps": float(config["rms_norm_eps"]),
-        "tied": bool(config["tie_word_embeddings"]),
-        "dtype": config["torch_dtype"],
-        "block_size": int(serving["block_size"]),
-    }
-
-
-def program_config(config: Dict[str, Any], name: str):
-    """The program's ``ModelConfig`` for a configuration file."""
-    import jax.numpy as jnp
-    from repro.models.common import ModelConfig
-
-    s = model_shape(config)
-    return ModelConfig(
-        name=name, family=s["family"], num_layers=s["layers"],
-        d_model=s["d_model"], num_heads=s["heads"], num_kv_heads=s["kv_heads"],
-        head_dim=s["head_dim"], d_ff=0 if s["family"] == "moe" else s["d_ff"],
-        moe_d_ff=s["d_ff"] if s["family"] == "moe" else 0,
-        num_experts=s["experts"], top_k=s["top_k"], qk_norm=s["qk_norm"],
-        vocab_size=s["vocab"], rope_theta=s["rope_theta"], norm_eps=s["norm_eps"],
-        tie_embeddings=s["tied"], dtype=getattr(jnp, s["dtype"]),
-        block_size=s["block_size"])
+    """The sizes of a ``configs/<config>.json``, as its family reads them."""
+    return family(config["serving"]["family"]).shape(config)

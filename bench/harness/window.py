@@ -7,9 +7,12 @@ reduced device trace. Each ``metrics/<name>.py`` reads one number from it.
 from __future__ import annotations
 
 import dataclasses
+from types import ModuleType
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
+
+from harness import spec
 
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
@@ -31,6 +34,12 @@ class Run:
     setup_s: float
     trace: Optional[Any] = None        # harness.trace.Reduced, traced runs only
     lateness: List[float] = dataclasses.field(default_factory=list)
+
+    @property
+    def model(self) -> ModuleType:
+        """The configuration's family module (``reference/<family>.py``): its
+        FLOP counts and paged-attention calls."""
+        return spec.family(self.shape["family"])
 
     @property
     def seconds(self) -> float:

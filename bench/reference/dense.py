@@ -17,15 +17,24 @@ rows, so a 17k-token sequence fits beside the weights.
 product rounded to float8 e4m3 (absmax scale per output channel for weights,
 per token for activations): the control, one precision below the bfloat16
 the configurations state.
+
+The module is also everything else the benchmark knows of the family (see
+``harness/spec.py``): the sizes it reads from a configuration file
+(``shape``), the program's ``ModelConfig`` (``program_config``), the weight
+tree (``weight_shapes``), the FLOPs of a prefill chunk and of a decode token
+(``prefill_flops``, ``decode_flops``) and the paged-attention calls of a
+decode step (``attention_calls``).
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+Tree = Dict[str, Any]
 
 HI = jax.lax.Precision.HIGHEST
 PAD = 1024
@@ -134,3 +143,120 @@ def run(weights, shape: Dict[str, Any], tokens: np.ndarray, first: int,
 def logits(weights, shape: Dict[str, Any], tokens: np.ndarray, first: int,
            fp8: bool = False) -> np.ndarray:
     return run(weights, shape, tokens, first, fp8, swiglu)
+
+
+def shape(config: Dict[str, Any]) -> Dict[str, Any]:
+    """The sizes every consumer (weights, reference, FLOP counts) reads,
+    under one set of names, from a ``configs/<config>.json``."""
+    serving = config["serving"]
+    return {
+        "family": serving["family"],
+        "layers": config["num_hidden_layers"],
+        "d_model": config["hidden_size"],
+        "heads": config["num_attention_heads"],
+        "kv_heads": config["num_key_value_heads"],
+        "head_dim": config.get("head_dim") or serving["head_dim"],
+        "d_ff": config["intermediate_size"],
+        "experts": 0,
+        "top_k": 0,
+        "vocab": config["vocab_size"],
+        "qk_norm": bool(serving.get("qk_norm", False)),
+        "rope_theta": float(config["rope_theta"]),
+        "norm_eps": float(config["rms_norm_eps"]),
+        "tied": bool(config["tie_word_embeddings"]),
+        "dtype": config["torch_dtype"],
+        "block_size": int(serving["block_size"]),
+    }
+
+
+def program_config(config: Dict[str, Any], name: str):
+    """The program's ``ModelConfig`` for a configuration file."""
+    from repro.models.common import ModelConfig
+
+    s = shape(config)
+    return ModelConfig(
+        name=name, family=s["family"], num_layers=s["layers"],
+        d_model=s["d_model"], num_heads=s["heads"], num_kv_heads=s["kv_heads"],
+        head_dim=s["head_dim"], d_ff=s["d_ff"], qk_norm=s["qk_norm"],
+        vocab_size=s["vocab"], rope_theta=s["rope_theta"], norm_eps=s["norm_eps"],
+        tie_embeddings=s["tied"], dtype=getattr(jnp, s["dtype"]),
+        block_size=s["block_size"])
+
+
+def weight_tree(s: Dict[str, Any], ffn: Tree) -> Tree:
+    """{path: (shape, init)} of the program's stacked-layer transformer with
+    the FFN leaves ``ffn``: ``embed``, ``final_norm`` and ``layers`` with
+    ``wq (L, d, H, hd)``, ``wk``/``wv (L, d, KV, hd)``, ``wo (L, H, hd, d)``,
+    ``q_norm``/``k_norm (L, hd)`` where the configuration has qk-norm, and
+    ``norm_attn``/``norm_mlp (L, d)``; ``unembed`` where the embedding is not
+    tied. ``init`` is a projection's fan-in, or a name in
+    ``harness/weights.py``'s ``INITS`` (``None`` for a norm)."""
+    L, d, H, KV, hd = s["layers"], s["d_model"], s["heads"], s["kv_heads"], s["head_dim"]
+    layer: Tree = {
+        "wq": ((L, d, H, hd), d), "wk": ((L, d, KV, hd), d),
+        "wv": ((L, d, KV, hd), d), "wo": ((L, H, hd, d), H * hd),
+        "norm_attn": ((L, d), None), "norm_mlp": ((L, d), None),
+    }
+    if s["qk_norm"]:
+        layer["q_norm"] = ((L, hd), None)
+        layer["k_norm"] = ((L, hd), None)
+    layer.update(ffn)
+    tree = {"embed": ((s["vocab"], d), "embed"), "final_norm": ((d,), None),
+            "layers": layer}
+    if not s["tied"]:
+        tree["unembed"] = ((s["vocab"], d), "embed")
+    return tree
+
+
+def weight_shapes(s: Dict[str, Any]) -> Tree:
+    """The weight tree with a SwiGLU FFN: ``w_gate``/``w_up (L, d, f)``,
+    ``w_down (L, f, d)``."""
+    L, d, f = s["layers"], s["d_model"], s["d_ff"]
+    return weight_tree(s, {"w_gate": ((L, d, f), d), "w_up": ((L, d, f), d),
+                           "w_down": ((L, f, d), f)})
+
+
+# FLOPs: two per multiply-add of the weights a token passes through; causal
+# attention over ``n`` keys costs ``4 * heads * head_dim * n`` per layer
+# (scores and weighted sum); the logits head counts once per prefill call's
+# last position and once per decode token.
+
+def attn_params(s: Dict[str, Any]) -> int:
+    """Attention weights one token multiplies through in one layer."""
+    d, H, KV, hd = s["d_model"], s["heads"], s["kv_heads"], s["head_dim"]
+    return d * H * hd + 2 * d * KV * hd + H * hd * d
+
+
+def layer_params(s: Dict[str, Any]) -> int:
+    """Weights one token multiplies through in one layer."""
+    return attn_params(s) + 3 * s["d_model"] * s["d_ff"]
+
+
+def attn_pairs(offset: int, chunk: int) -> int:
+    """(query, key) pairs of a causal chunk of ``chunk`` queries at ``offset``."""
+    return chunk * offset + chunk * (chunk + 1) // 2
+
+
+def stack_flops(s: Dict[str, Any], params: int, tokens: int, pairs: int) -> int:
+    """``tokens`` tokens through every layer, ``params`` weights a layer and
+    ``pairs`` attended (query, key) pairs, then one row of logits."""
+    L, H, hd = s["layers"], s["heads"], s["head_dim"]
+    return (L * (2 * params * tokens + 4 * H * hd * pairs)
+            + 2 * s["vocab"] * s["d_model"])
+
+
+def prefill_flops(s: Dict[str, Any], offset: int, chunk: int) -> int:
+    """A causal chunk of ``chunk`` tokens after ``offset`` cached ones."""
+    return stack_flops(s, layer_params(s), chunk, attn_pairs(offset, chunk))
+
+
+def decode_flops(s: Dict[str, Any], cached: int) -> int:
+    """One token with ``cached`` tokens already in the cache (it attends to
+    those and to itself)."""
+    return stack_flops(s, layer_params(s), 1, cached + 1)
+
+
+def attention_calls(s: Dict[str, Any]) -> List[Tuple[int, int, int, int]]:
+    """(KV heads, query group, head_dim, window) of each paged-attention call
+    of a decode step: one full-attention call a layer (window 0)."""
+    return [(s["kv_heads"], s["heads"] // s["kv_heads"], s["head_dim"], 0)] * s["layers"]

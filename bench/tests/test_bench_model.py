@@ -1,4 +1,5 @@
-"""The benchmark's model arithmetic, for the dense configuration and for a
+"""The benchmark's model arithmetic, as the family modules
+(``reference/<family>.py``) give it, for the dense configuration and for a
 mixture of experts at granite-moe-1b-a400m's published sizes: FLOP counts
 against hand counts, and the weight tree against the program's."""
 import json
@@ -10,6 +11,8 @@ import pytest
 from harness import spec
 from harness import weights as W
 from harness.spec import load_module
+
+LEAF = lambda x: isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "configs")
 
@@ -35,46 +38,45 @@ def shape(name):
     return spec.model_shape(config(name))
 
 
-@pytest.fixture(scope="module")
-def mfu():
-    return load_module("metrics", "step_mfu")
+def family(name):
+    return spec.family(shape(name)["family"])
 
 
-def test_qwen3_flops_by_hand(mfu):
+def test_qwen3_flops_by_hand():
     s = shape("qwen3-1.7b")
+    model = family("qwen3-1.7b")
     # per layer: q,k,v,o = 4 * 2048*2048 / (k,v at 1024 wide: 2*2048*1024) -> 12,582,912
     # SwiGLU 3 * 2048 * 6144 = 37,748,736
-    assert mfu.layer_params(s) == 12_582_912 + 37_748_736
+    assert model.layer_params(s) == 12_582_912 + 37_748_736
     # one token with nothing cached: 28 layers of (2 * 50,331,648 + 4*16*128*1)
     # plus the tied logits 2 * 151,936 * 2,048
-    assert mfu.decode_flops(s, 0) == 28 * (100_663_296 + 8_192) + 622_329_856
+    assert model.decode_flops(s, 0) == 28 * (100_663_296 + 8_192) + 622_329_856
     # a 2,048-token chunk after 2,048 cached: pairs 2048*2048 + 2048*2049/2
     pairs = 2048 * 2048 + 2048 * 2049 // 2
-    assert mfu.prefill_flops(s, 2048, 2048) == \
+    assert model.prefill_flops(s, 2048, 2048) == \
         28 * (2 * 50_331_648 * 2048 + 4 * 16 * 128 * pairs) + 622_329_856
 
 
-def test_granite_flops_count_top8_experts(mfu):
+def test_granite_flops_count_top8_experts():
     s = shape("granite-moe-1b-a400m")
+    model = family("granite-moe-1b-a400m")
     # attention 3 * 1024*1024 + ... = 3,145,728; router 1024*32; 8 experts of 3*1024*512
-    assert mfu.layer_params(s) == 3_145_728 + 32_768 + 12_582_912
-    assert mfu.decode_flops(s, 0) == 24 * (2 * 15_761_408 + 4_096) + 2 * 49_155 * 1_024
+    assert model.layer_params(s) == 3_145_728 + 32_768 + 12_582_912
+    assert model.decode_flops(s, 0) == 24 * (2 * 15_761_408 + 4_096) + 2 * 49_155 * 1_024
     # active parameters about 0.43 B: two FLOPs each
-    assert mfu.decode_flops(s, 0) == pytest.approx(2 * 0.43e9, rel=0.01)
+    assert model.decode_flops(s, 0) == pytest.approx(2 * 0.43e9, rel=0.01)
 
 
 @pytest.mark.parametrize("name", ["qwen3-1.7b", "granite-moe-1b-a400m"])
 def test_weight_tree_is_the_programs(name):
     from repro.models.api import get_model
-    cfg = spec.program_config(config(name), name)
+    cfg = family(name).program_config(config(name), name)
     want = jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
-    tree = W.shapes(shape(name))
-    leaves = lambda t: jax.tree.leaves(t, is_leaf=lambda x: isinstance(x, tuple)
-                                       and len(x) == 2 and isinstance(x[0], tuple))
+    tree = family(name).weight_shapes(shape(name))
     assert jax.tree.structure(want) == jax.tree.structure(
-        jax.tree.map(lambda x: 0, tree, is_leaf=lambda x: isinstance(x, tuple)
-                     and len(x) == 2 and isinstance(x[0], tuple)))
-    assert [w.shape for w in jax.tree.leaves(want)] == [t[0] for t in leaves(tree)]
+        jax.tree.map(lambda x: 0, tree, is_leaf=LEAF))
+    assert [w.shape for w in jax.tree.leaves(want)] == \
+        [t[0] for t in jax.tree.leaves(tree, is_leaf=LEAF)]
 
 
 def test_weights_are_made_from_the_seed():
@@ -89,12 +91,14 @@ def test_weights_are_made_from_the_seed():
 
 def test_paged_attention_work_by_hand_for_both_configurations():
     attn = load_module("metrics", "paged_attn_roofline")
-    q = shape("qwen3-1.7b")
+    # one full-attention call a layer, 8 KV heads in groups of 2, head_dim 128
+    assert family("qwen3-1.7b").attention_calls(shape("qwen3-1.7b")) == [(8, 2, 128, 0)] * 28
+    q = family("qwen3-1.7b").attention_calls(shape("qwen3-1.7b"))[0]
     # 4,096 cached tokens: scores and weighted sum 4 x 16 heads x 128 per token
     assert attn.call_flops(q, [4096]) == 4 * 16 * 128 * 4096
     # K and V of 4,096 tokens at 8 heads of 128 in bf16, plus q, out, m and l
     assert attn.call_bytes(q, [4096]) == 16_777_216 + 2 * 16 * 128 * 2 + 2 * 16 * 4
-    g = shape("granite-moe-1b-a400m")
+    g = family("granite-moe-1b-a400m").attention_calls(shape("granite-moe-1b-a400m"))[0]
     assert attn.call_bytes(g, [1000, 24]) == 2 * 8 * 64 * 1024 * 2 + 2 * (2 * 16 * 64 * 2 + 2 * 16 * 4)
     # memory-bound on a v5e: 2 FLOPs a byte against a ridge of 240
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}

@@ -110,14 +110,15 @@ def test_reduce_keeps_the_text_of_custom_calls_only():
 def test_paged_attention_roofline_from_trace_and_spans():
     from harness.spec import load_module
     mod = load_module("metrics", "paged_attn_roofline")
-    shape = {"layers": 2, "heads": 16, "kv_heads": 8, "head_dim": 128}
+    shape = {"family": "dense", "layers": 2, "heads": 16, "kv_heads": 8, "head_dim": 128}
     run = kernel_run(shape, [100, 300])
+    call = (8, 2, 128, 0)
     # the two attention events, 4000 ns; the transfer and append calls are not its
     assert sum(o.dur for o, _ in run.trace.kernels(mod.CALL)) == 4000
     # bytes: K,V of 400 tokens, 8 heads of 128, bf16 = 1,638,400; q and out
     # 2 x 16 x 128 x 2 B and m, l 2 x 16 x 4 B, for 2 sequences = 16,640
-    assert mod.call_bytes(shape, [100, 300]) == 1_638_400 + 16_640
-    assert mod.call_flops(shape, [100, 300]) == 4 * 16 * 128 * 400
+    assert mod.call_bytes(call, [100, 300]) == 1_638_400 + 16_640
+    assert mod.call_flops(call, [100, 300]) == 4 * 16 * 128 * 400
     # bound by bytes at 1e9 B/s: 2 layers x 1,655,040 ns over 4000 ns of device time
     assert mod.read(run) == pytest.approx(100 * 2 * 1_655_040 / 4000)
 
@@ -125,14 +126,16 @@ def test_paged_attention_roofline_from_trace_and_spans():
 def test_paged_attention_of_another_shape_is_not_counted():
     from harness.spec import load_module
     mod = load_module("metrics", "paged_attn_roofline")
-    run = kernel_run({"layers": 2, "heads": 16, "kv_heads": 8, "head_dim": 64}, [100])
+    run = kernel_run({"family": "dense", "layers": 2, "heads": 16, "kv_heads": 8, "head_dim": 64},
+                     [100])
     assert mod.read(run) is None
 
 
 def test_kv_transfer_roofline_counts_pool_to_pool_calls_only():
     from harness.spec import load_module
     mod = load_module("metrics", "kv_transfer_roofline")
-    run = kernel_run({"layers": 2, "heads": 16, "kv_heads": 8, "head_dim": 128}, [1])
+    run = kernel_run({"family": "dense", "layers": 2, "heads": 16, "kv_heads": 8,
+                      "head_dim": 128}, [1])
     calls = run.trace.kernels(mod.CALL)
     assert [int(m.group(4)) for _, m in calls] == [2304]
     # 2304 pages of 128 x 128 bf16, read and written: 150,994,944 B at 1e9 B/s
@@ -142,7 +145,8 @@ def test_kv_transfer_roofline_counts_pool_to_pool_calls_only():
 
 def test_kernel_readers_are_silent_without_a_trace():
     from harness.spec import load_module
-    run = kernel_run({"layers": 2, "heads": 16, "kv_heads": 8, "head_dim": 128}, [1])
+    run = kernel_run({"family": "dense", "layers": 2, "heads": 16, "kv_heads": 8,
+                      "head_dim": 128}, [1])
     run.trace = None
     for name in ("paged_attn_roofline", "kv_transfer_roofline", "device_idle_frac"):
         assert load_module("metrics", name).read(run) is None

@@ -81,8 +81,8 @@ def check_kernels(cfg, key) -> None:
     payload = bs * KV * hd
     nb = 128                     # holds the longest prompt's 94 pages
     k = jax.random.split(key, 6)
-    src = jax.random.normal(k[0], (nb, L, 2, payload), cfg.dtype)
-    dst = jax.random.normal(k[1], (nb, L, 2, payload), cfg.dtype)
+    src = jax.random.normal(k[0], (nb, L, 2, KV, bs, hd), cfg.dtype)
+    dst = jax.random.normal(k[1], (nb, L, 2, KV, bs, hd), cfg.dtype)
     n = 3 * L * 2
     sp = jax.random.permutation(k[2], nb * L * 2)[:n].astype(jnp.int32)
     dp = jax.random.permutation(k[3], nb * L * 2)[:n].astype(jnp.int32)
@@ -99,15 +99,15 @@ def check_kernels(cfg, key) -> None:
     bt = jax.random.permutation(k[4], nb)[:maxb]
     bt = jnp.tile(bt, (b, 1)).astype(jnp.int32)
     q = jax.random.normal(k[5], (b, cfg.num_heads, hd), cfg.dtype)
-    pages = src[:, 0]
     attend = jax.jit(lambda *a: paged_decode_attention(
-        *a, block_size=bs, return_stats=True))
-    check(_has_mosaic(attend, q, pages, bt, lengths),
+        *a, return_stats=True))
+    check(_has_mosaic(attend, q, src, 0, bt, lengths),
           "paged_decode_attention not Mosaic")
-    out, m, l = attend(q, pages, bt, lengths)
+    out, m, l = attend(q, src, 0, bt, lengths)
     with jax.default_matmul_precision("highest"):
         r_out, r_m, r_l = paged_decode_attention_stats_ref(
-            q.astype(jnp.float32), pages.astype(jnp.float32), bt, lengths, bs)
+            q.astype(jnp.float32), src[:, :1].astype(jnp.float32), 0, bt,
+            lengths)
     for name, a, r, tol in (("out", out, r_out, ATTN_OUT_TOL),
                             ("m", m, r_m, ATTN_STATS_TOL),
                             ("l", l, r_l, ATTN_STATS_TOL)):

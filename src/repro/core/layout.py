@@ -14,8 +14,19 @@ FlowKV transposes block to the major axis::
 making *one block* carry K and V for *all* layers contiguously, so the same
 request needs only ``n`` transfers before alignment (and ideally 1 after).
 
-``H`` here is the flattened per-(layer, k/v, block) payload:
-``block_size * num_kv_heads * head_dim``.
+``H`` is one (block, layer, k/v) *page*: ``block_size * num_kv_heads *
+head_dim`` elements (``payload``). The pool stores each page unflattened and
+kv-head-major, ``(num_kv_heads, block_size, head_dim)``, so the stored arrays
+are::
+
+    FLOWKV: (B, L, 2, KV, bs, hd)        VLLM: (L, 2, B, KV, bs, hd)
+
+That is the shape every kernel reads: paged attention takes one page at
+``(block, layer)`` straight from the pool, the decode append rewrites pages
+in place, and the transfer views pages as ``(pages, KV * bs, hd)`` by
+merging leading dims only. On a TPU the minor ``(bs, hd)`` dims are whole
+tiles for the served models (32 x 128 in bf16), so none of these views
+copies the pool.
 
 Everything in this module is data-plane: the arrays are real ``jnp`` arrays
 (tiny in tests, ShapeDtypeStructs in the dry-run).
@@ -32,8 +43,8 @@ import jax.numpy as jnp
 
 
 class KVLayout(enum.Enum):
-    VLLM = "vllm"        # (L, 2, B, H)  — layer-major baseline
-    FLOWKV = "flowkv"    # (B, L, 2, H)  — block-major, paper Eq. 5
+    VLLM = "vllm"        # (L, 2, B, KV, bs, hd)  — layer-major baseline
+    FLOWKV = "flowkv"    # (B, L, 2, KV, bs, hd)  — block-major, paper Eq. 5
 
     @property
     def block_axis(self) -> int:
@@ -58,10 +69,15 @@ class KVCacheSpec:
         return self.block_size * self.num_kv_heads * self.head_dim
 
     @property
+    def page_shape(self) -> Tuple[int, int, int]:
+        """One stored page, kv-head-major: (num_kv_heads, block_size, head_dim)."""
+        return (self.num_kv_heads, self.block_size, self.head_dim)
+
+    @property
     def shape(self) -> Tuple[int, ...]:
         if self.layout is KVLayout.VLLM:
-            return (self.num_layers, 2, self.num_blocks, self.payload)
-        return (self.num_blocks, self.num_layers, 2, self.payload)
+            return (self.num_layers, 2, self.num_blocks, *self.page_shape)
+        return (self.num_blocks, self.num_layers, 2, *self.page_shape)
 
     @property
     def bytes_per_block(self) -> int:
@@ -90,23 +106,19 @@ class KVCacheSpec:
         """
         return self.num_layers * 2 if self.layout is KVLayout.VLLM else 1
 
-    def page_view_shape(self) -> Tuple[int, int, int, int]:
-        """Per-block unflattened page shape (block_size, kv_heads, head_dim) x (L,2)."""
-        return (self.num_layers, 2, self.block_size, self.num_kv_heads * self.head_dim)
-
 
 def alloc_cache(spec: KVCacheSpec) -> jax.Array:
     return jnp.zeros(spec.shape, dtype=spec.dtype)
 
 
 def vllm_to_flowkv(cache: jax.Array) -> jax.Array:
-    """(L, 2, B, H) -> (B, L, 2, H)."""
-    return jnp.transpose(cache, (2, 0, 1, 3))
+    """(L, 2, B, KV, bs, hd) -> (B, L, 2, KV, bs, hd)."""
+    return jnp.transpose(cache, (2, 0, 1, 3, 4, 5))
 
 
 def flowkv_to_vllm(cache: jax.Array) -> jax.Array:
-    """(B, L, 2, H) -> (L, 2, B, H)."""
-    return jnp.transpose(cache, (1, 2, 0, 3))
+    """(B, L, 2, KV, bs, hd) -> (L, 2, B, KV, bs, hd)."""
+    return jnp.transpose(cache, (1, 2, 0, 3, 4, 5))
 
 
 def convert(cache: jax.Array, src: KVLayout, dst: KVLayout) -> jax.Array:
@@ -119,44 +131,43 @@ def convert(cache: jax.Array, src: KVLayout, dst: KVLayout) -> jax.Array:
 
 def write_block(cache: jax.Array, spec: KVCacheSpec, block_id, layer: int,
                 k_page: jax.Array, v_page: jax.Array) -> jax.Array:
-    """Write one (layer, block) K/V page. Pages are (block_size, kv*hd) flats."""
-    k_flat = k_page.reshape(-1).astype(spec.dtype)
-    v_flat = v_page.reshape(-1).astype(spec.dtype)
+    """Write one (layer, block) K/V page given as (block_size, kv_heads, head_dim)."""
+    k = jnp.swapaxes(k_page, 0, 1).astype(spec.dtype)    # stored kv-head-major
+    v = jnp.swapaxes(v_page, 0, 1).astype(spec.dtype)
     if spec.layout is KVLayout.FLOWKV:
-        cache = cache.at[block_id, layer, 0].set(k_flat)
-        cache = cache.at[block_id, layer, 1].set(v_flat)
+        cache = cache.at[block_id, layer, 0].set(k)
+        cache = cache.at[block_id, layer, 1].set(v)
     else:
-        cache = cache.at[layer, 0, block_id].set(k_flat)
-        cache = cache.at[layer, 1, block_id].set(v_flat)
+        cache = cache.at[layer, 0, block_id].set(k)
+        cache = cache.at[layer, 1, block_id].set(v)
     return cache
 
 
 def read_block(cache: jax.Array, spec: KVCacheSpec, block_id, layer: int) -> Tuple[jax.Array, jax.Array]:
     """Read one (layer, block) K/V page back as (block_size, kv_heads, head_dim)."""
-    shape = (spec.block_size, spec.num_kv_heads, spec.head_dim)
     if spec.layout is KVLayout.FLOWKV:
         k = cache[block_id, layer, 0]
         v = cache[block_id, layer, 1]
     else:
         k = cache[layer, 0, block_id]
         v = cache[layer, 1, block_id]
-    return k.reshape(shape), v.reshape(shape)
+    return jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
 
 
 def gather_blocks(cache: jax.Array, spec: KVCacheSpec, block_ids) -> jax.Array:
     """Gather whole blocks (all layers, K+V) — the unit FlowKV transfers.
 
-    Returns (n, L, 2, H) regardless of source layout.
+    Returns (n, L, 2, KV, bs, hd) regardless of source layout.
     """
     idx = jnp.asarray(block_ids, dtype=jnp.int32)
     if spec.layout is KVLayout.FLOWKV:
         return jnp.take(cache, idx, axis=0)
-    return jnp.transpose(jnp.take(cache, idx, axis=2), (2, 0, 1, 3))
+    return vllm_to_flowkv(jnp.take(cache, idx, axis=2))
 
 
-def scatter_blocks(cache: jax.Array, spec: KVCacheSpec, block_ids, payload: jax.Array) -> jax.Array:
-    """Scatter (n, L, 2, H) payload into the destination pool's blocks."""
+def scatter_blocks(cache: jax.Array, spec: KVCacheSpec, block_ids, blocks: jax.Array) -> jax.Array:
+    """Scatter (n, L, 2, KV, bs, hd) blocks into the destination pool."""
     idx = jnp.asarray(block_ids, dtype=jnp.int32)
     if spec.layout is KVLayout.FLOWKV:
-        return cache.at[idx].set(payload.astype(cache.dtype))
-    return cache.at[:, :, idx, :].set(jnp.transpose(payload, (1, 2, 0, 3)).astype(cache.dtype))
+        return cache.at[idx].set(blocks.astype(cache.dtype))
+    return cache.at[:, :, idx].set(flowkv_to_vllm(blocks).astype(cache.dtype))

@@ -153,24 +153,28 @@ def shard_slice_spec(spec: L.KVCacheSpec, shard: ShardSpec) -> L.KVCacheSpec:
     return dataclasses.replace(spec, num_kv_heads=shard.heads_per_shard)
 
 
-def fine_page_rows(coarse_pages: np.ndarray, block_size: int,
-                   local_heads: int, head_lo: int, head_hi: int) -> np.ndarray:
-    """Rows of a shard pool's fine ``(-1, head_dim)`` view covered by a
-    head-range slice of the given coarse pages.
+def head_planes(coarse_pages: np.ndarray, local_heads: int, head_lo: int,
+                head_hi: int) -> np.ndarray:
+    """Ids of a shard pool's head planes covered by a head-range slice of the
+    given pages.
 
     ``coarse_pages`` are flat page ids under the shard's per-shard spec
-    (``DescriptorTable.page_ids``); each coarse page is ``block_size *
-    local_heads`` fine rows, laid out slot-major then head-minor, so the row
-    for (page p, slot t, local head h) is ``(p*block_size + t)*local_heads
-    + h``. Restricting h to ``[head_lo, head_hi)`` (LOCAL indices) selects
-    exactly one shard-pair's head intersection — the payload one fused
-    ``kv_transfer`` dispatch moves.
+    (``DescriptorTable.page_ids``). A page is stored kv-head-major, ``(KV,
+    block_size, head_dim)``, so it is ``local_heads`` consecutive head planes
+    of ``(block_size, head_dim)``, and the plane of (page p, local head h)
+    is ``p * local_heads + h``. Restricting h to ``[head_lo, head_hi)``
+    (LOCAL indices) selects exactly one shard-pair's head intersection —
+    the payload one fused ``kv_transfer`` dispatch moves.
     """
-    t = np.arange(block_size, dtype=np.int64)
     h = np.arange(head_lo, head_hi, dtype=np.int64)
-    rows = (coarse_pages.astype(np.int64)[:, None, None] * block_size
-            + t[None, :, None]) * local_heads + h[None, None, :]
-    return rows.reshape(-1).astype(np.int32)
+    planes = coarse_pages.astype(np.int64)[:, None] * local_heads + h[None, :]
+    return planes.reshape(-1).astype(np.int32)
+
+
+def head_plane_view(pool: jax.Array) -> jax.Array:
+    """A pool as pages of one kv head, ``(-1, 1, block_size, head_dim)``:
+    leading dims merged, so on a TPU a bitcast of the stored pool."""
+    return pool.reshape(-1, 1, *pool.shape[-2:])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,8 +212,9 @@ class DescriptorTable:
     def page_ids(self, spec: L.KVCacheSpec, side: str) -> np.ndarray:
         """Flattened page ids for one side, honouring that side's layout.
 
-        FLOWKV pools (B, L, 2, H) flatten to page ``block*L*2 + layer*2 + kv``;
-        VLLM pools (L, 2, B, H) to ``(layer*2 + kv)*B + block``.
+        FLOWKV pools (B, L, 2, KV, bs, hd) flatten to page ``block*L*2 +
+        layer*2 + kv``; VLLM pools (L, 2, B, KV, bs, hd) to
+        ``(layer*2 + kv)*B + block``.
         """
         blocks = self.src_block if side == "src" else self.dst_block
         if spec.layout is L.KVLayout.FLOWKV:
@@ -446,7 +451,7 @@ class TransferPlanner:
         """Bidirectional segment alignment over the FlowKV layout."""
         if self.spec.layout is not L.KVLayout.FLOWKV:
             raise ValueError(
-                "flowkv schedule requires the FLOWKV (B, L, 2, H) layout; "
+                "flowkv schedule requires the FLOWKV (B, L, 2, ...) layout; "
                 f"got {self.spec.layout}"
             )
         src_blocks, dst_blocks = list(src_blocks), list(dst_blocks)
@@ -523,8 +528,8 @@ class TransferEngine:
             raise ValueError("src/dst pools must agree on per-block payload")
         if self.src_spec.num_layers != self.dst_spec.num_layers:
             raise ValueError("src/dst pools must agree on layer count")
-        if self.src_spec.payload != self.dst_spec.payload:
-            raise ValueError("src/dst pools must agree on page payload")
+        if self.src_spec.page_shape != self.dst_spec.page_shape:
+            raise ValueError("src/dst pools must agree on page shape")
         self.interpret = interpret_mode(interpret)
         self.planner = TransferPlanner(src_spec)
         self.num_dispatches = 0
@@ -553,10 +558,11 @@ class ShardedTransferEngine:
     per-shard spec's FLOWKV pool — same blocks and layers, only its
     contiguous kv-head slice). A plan lowers to exactly ONE fused
     ``kv_transfer`` dispatch per overlapping (src_shard, dst_shard) pair:
-    the pair's coarse descriptor pages expand to fine ``(-1, head_dim)``
-    rows restricted to the pair's head intersection — the same flat-page
-    trick the cross-layout engine uses, one granularity finer. head_dim is
-    degree-invariant, so the fine payload matches on both sides for ANY
+    the pair's coarse descriptor pages expand to the head planes
+    ``(block_size, head_dim)`` of the pair's head intersection
+    (:func:`head_planes`, over :func:`head_plane_view`) — the same
+    flat-page trick the cross-layout engine uses, one head at a time. A
+    head plane is degree-invariant, so it matches on both sides for ANY
     (tp_src, tp_dst) combination; per-pair bytes sum exactly to the
     unsharded plan's bytes.
     """
@@ -592,13 +598,13 @@ class ShardedTransferEngine:
         s, d, lo, hi = pair
         src_sspec = shard_slice_spec(self.src_spec, self.src_shard)
         dst_sspec = shard_slice_spec(self.dst_spec, self.dst_shard)
-        src_rows = fine_page_rows(
-            table.page_ids(src_sspec, "src"), self.src_spec.block_size,
-            src_sspec.num_kv_heads, lo - self.src_shard.head_range(s)[0],
+        src_rows = head_planes(
+            table.page_ids(src_sspec, "src"), src_sspec.num_kv_heads,
+            lo - self.src_shard.head_range(s)[0],
             hi - self.src_shard.head_range(s)[0])
-        dst_rows = fine_page_rows(
-            table.page_ids(dst_sspec, "dst"), self.dst_spec.block_size,
-            dst_sspec.num_kv_heads, lo - self.dst_shard.head_range(d)[0],
+        dst_rows = head_planes(
+            table.page_ids(dst_sspec, "dst"), dst_sspec.num_kv_heads,
+            lo - self.dst_shard.head_range(d)[0],
             hi - self.dst_shard.head_range(d)[0])
         return src_rows, dst_rows
 
@@ -610,14 +616,13 @@ class ShardedTransferEngine:
         out = list(dst_pools)
         if len(table) == 0:
             return out
-        hd = self.src_spec.head_dim
         src_sspec = shard_slice_spec(self.src_spec, self.src_shard)
         dst_sspec = shard_slice_spec(self.dst_spec, self.dst_shard)
         for pair in shard_pairs(self.src_shard, self.dst_shard):
             s, d, _, _ = pair
             src_rows, dst_rows = self._pair_rows(table, pair)
-            src_flat = src_pools[s].reshape(-1, hd)
-            dst_flat = out[d].reshape(-1, hd)
+            src_flat = head_plane_view(src_pools[s])
+            dst_flat = head_plane_view(out[d])
             executor = _get_executor(src_sspec, dst_sspec,
                                      plan.schedule, self.interpret)
             self.num_dispatches += 1
@@ -660,10 +665,11 @@ def _rows_equal(src: jax.Array, dst: jax.Array, src_idx: jax.Array,
                 dst_idx: jax.Array, step: int) -> jax.Array:
     """Device flag: every indexed dst row equals its src row, bit for bit.
 
-    ``src_idx`` / ``dst_idx`` are ``(ndim - 1, n)`` leading indices of each
-    row in its array's native layout (row ``i`` of one pairs with row ``i``
-    of the other). Rows are gathered and compared ``step`` at a time, so the
-    check never holds all ``n`` gathered rows at once.
+    ``src_idx`` / ``dst_idx`` are ``(lead, n)`` leading indices of each row
+    in its array's native layout (a row is what the trailing dims hold; row
+    ``i`` of one pairs with row ``i`` of the other). Rows are gathered and
+    compared ``step`` at a time, so the check never holds all ``n``
+    gathered rows at once.
     """
     def body(i, ok):
         lo = i * step
@@ -676,13 +682,14 @@ def _rows_equal(src: jax.Array, dst: jax.Array, src_idx: jax.Array,
 
 
 def _rows_equal_flag(src: jax.Array, src_rows: np.ndarray, dst: jax.Array,
-                     dst_rows: np.ndarray) -> jax.Array:
-    """Launch :func:`_rows_equal` on flat row ids over each array's leading
-    axes (the last axis is the row payload); returns the device flag."""
-    rows_per_step = max(_CHECK_STEP_BYTES // (src.shape[-1] * src.itemsize), 1)
+                     dst_rows: np.ndarray, lead: int) -> jax.Array:
+    """Launch :func:`_rows_equal` on flat row ids over each array's first
+    ``lead`` axes (the trailing axes are one row); returns the device flag."""
+    row_bytes = math.prod(src.shape[lead:]) * src.itemsize
+    rows_per_step = max(_CHECK_STEP_BYTES // row_bytes, 1)
     step = math.gcd(len(src_rows), 1 << (rows_per_step.bit_length() - 1))
-    src_idx = np.stack(np.unravel_index(src_rows, src.shape[:-1]))
-    dst_idx = np.stack(np.unravel_index(dst_rows, dst.shape[:-1]))
+    src_idx = np.stack(np.unravel_index(src_rows, src.shape[:lead]))
+    dst_idx = np.stack(np.unravel_index(dst_rows, dst.shape[:lead]))
     return _rows_equal(src, dst, jnp.asarray(src_idx, jnp.int32),
                        jnp.asarray(dst_idx, jnp.int32), step)
 
@@ -705,7 +712,8 @@ def verify_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
     bucket = check_bucket(len(table))
     flag = _rows_equal_flag(
         src_pool, _pad_pages(table.page_ids(src_spec, "src"), bucket),
-        dst_pool, _pad_pages(table.page_ids(dst_spec, "dst"), bucket))
+        dst_pool, _pad_pages(table.page_ids(dst_spec, "dst"), bucket),
+        lead=src_pool.ndim - 3)
     return bool(jax.device_get(flag))
 
 
@@ -715,10 +723,10 @@ def verify_sharded_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
                             dst_pools: Sequence[jax.Array]) -> bool:
     """Shard-aware twin of :func:`verify_transfer`.
 
-    Compares each overlapping (src_shard, dst_shard) pair's fine
-    ``(-1, head_dim)`` rows — exactly the rows the per-pair dispatch moved,
-    expanded from the bucket-padded page tables — on the device, and reads
-    back one flag for all pairs. The plan must carry shard topology (see
+    Compares each overlapping (src_shard, dst_shard) pair's head planes —
+    exactly the planes the per-pair dispatch moved, expanded from the
+    bucket-padded page tables and indexed in each pool's native shape — on
+    the device, and reads back one flag for all pairs. The plan must carry shard topology (see
     ``TransferPlan.src_shard`` / ``dst_shard``); pools are per-shard lists.
     """
     table = plan.to_descriptors()
@@ -729,21 +737,18 @@ def verify_sharded_transfer(plan: TransferPlan, src_spec: L.KVCacheSpec,
     heads = (plan.src_shard or plan.dst_shard).num_kv_heads
     src_shard = plan.src_shard or ShardSpec(1, heads)
     dst_shard = plan.dst_shard or ShardSpec(1, heads)
-    hd = src_spec.head_dim
     bucket = check_bucket(len(table))
 
-    def rows(spec, shard, shard_idx, lo, hi, side):
+    def planes(spec, shard, shard_idx, lo, hi, side):
         sspec = shard_slice_spec(spec, shard)
         pages = _pad_pages(table.page_ids(sspec, side), bucket)
         base = shard.head_range(shard_idx)[0]
-        return fine_page_rows(pages, spec.block_size, sspec.num_kv_heads,
-                              lo - base, hi - base)
+        return head_planes(pages, sspec.num_kv_heads, lo - base, hi - base)
 
     flags = [_rows_equal_flag(
-        src_pools[s].reshape(-1, hd),
-        rows(src_spec, src_shard, s, lo, hi, "src"),
-        dst_pools[d].reshape(-1, hd),
-        rows(dst_spec, dst_shard, d, lo, hi, "dst"))
+        src_pools[s], planes(src_spec, src_shard, s, lo, hi, "src"),
+        dst_pools[d], planes(dst_spec, dst_shard, d, lo, hi, "dst"),
+        lead=src_pools[s].ndim - 2)
         for s, d, lo, hi in shard_pairs(src_shard, dst_shard)]
     return bool(jax.device_get(jnp.all(jnp.stack(flags))))
 
@@ -757,8 +762,8 @@ def _pools_of(kv) -> List[jax.Array]:
 def pool_transfer_engine(src_kv, dst_kv, *, interpret: Optional[bool] = None):
     """Build the transfer engine matching two pool ports' shard topology.
 
-    Both-unsharded stays on the classic :class:`TransferEngine` (whole-payload
-    flat pages, one dispatch per plan); any sharded side lowers through
+    Both-unsharded stays on the classic :class:`TransferEngine` (whole
+    pages, one dispatch per plan); any sharded side lowers through
     :class:`ShardedTransferEngine` (one dispatch per overlapping shard pair).
     Ports expose ``spec`` and, when sharded, ``tp`` / ``pools``
     (serving/kv_cache.ShardedKVCache).

@@ -40,7 +40,7 @@ DEFAULT_RULES: Dict[str, Tuple[Tuple[str, ...], ...]] = {
     # replication: page ids are global names shared by every shard's block
     # manager and transfer descriptor table, so sharding the page dim would
     # silently split the address space the descriptor plane indexes into.
-    # A paged pool shards only inside the payload (its kv-head slice; see
+    # A paged pool shards only on its kv-head axis (see
     # serving/kv_cache.ShardedKVCache), never across pages. Declared as an
     # empty candidate list (not just left out of the dict) so the intent
     # survives anyone extending the kv_seq fallback chain.
@@ -48,11 +48,12 @@ DEFAULT_RULES: Dict[str, Tuple[Tuple[str, ...], ...]] = {
     # replicated: embed, head_dim, seq, layers, groups, conv, state, lru_in
 }
 
-# Canonical logical axes of a FLOWKV paged pool (num_blocks, L, 2, payload).
+# Canonical logical axes of a FLOWKV paged pool (num_blocks, L, 2, KV, bs, hd).
 # The page dim must use "kv_pages" (never "kv_seq": the decode-cache length
 # fallback would shard page tables when num_blocks happens to divide the
 # model axis — see tests/test_sharding.py::test_paged_pool_never_shards_pages).
-PAGED_POOL_AXES: Tuple[Optional[str], ...] = ("kv_pages", "layers", None, None)
+PAGED_POOL_AXES: Tuple[Optional[str], ...] = (
+    "kv_pages", "layers", None, "kv_heads", None, "head_dim")
 
 
 class AbstractMesh:

@@ -147,7 +147,7 @@ def make_decode_step(model: Model, mesh: Mesh):
 def kv_transfer_specs(cfg: ModelConfig, mesh: Mesh, seq: int, batch: int):
     """ShapeDtypeStructs for the transfer program: the paged FlowKV pool.
 
-    Pool shape (num_blocks, L, 2, payload): block-major (paper Eq. 5), block
+    Pool shape (num_blocks, L, 2, KV, bs, hd): block-major (paper Eq. 5), block
     dim sharded (pod, data) so each pod/replica owns its page slab.
     """
     from repro.core.layout import KVCacheSpec

@@ -265,9 +265,9 @@ def sharded_decode_step_paged(shards: Sequence[Params], cfg: ModelConfig,
     """Sharded twin of ``transformer.decode_step_paged``.
 
     ``pools[s]`` is shard s's FLOWKV pool (same blocks/layers, its kv-head
-    slice of every payload). Each shard reads its own page plane through the
-    paged kernel and appends its slice of the batch's new K/V with its own
-    fused scatter — on a real mesh that is one dispatch per device, here
+    slice on axis 3). Each shard's pool is read in place by the paged kernel
+    and takes its slice of the batch's new K/V through its own fused
+    append — on a real mesh that is one dispatch per device, here
     ``tp`` calls inside one jitted step. The in-flight-token online-softmax
     merge runs ONCE on the concatenated kernel stats (the post-gather merge):
     its einsums are not bit-stable across kv-head extents, so a per-shard
@@ -286,13 +286,11 @@ def sharded_decode_step_paged(shards: Sequence[Params], cfg: ModelConfig,
         pos = jnp.broadcast_to(jnp.asarray(position), (hn.shape[0],))
         q1s, k1s, v1s, outs, ms, ls = [], [], [], [], [], []
         for lp, pool in zip(lps, pools):
-            pages = jax.lax.dynamic_index_in_dim(pool, layer, axis=1,
-                                                 keepdims=False)
             q, k_new, v_new = A.qkv_project(lp, hn, cfg, pos[:, None])
             q1s.append(q[:, 0]), k1s.append(k_new[:, 0]), v1s.append(v_new[:, 0])
             o, m, l = paged_decode_attention(
-                q[:, 0], pages, block_tables, pos, block_size=cfg.block_size,
-                interpret=interpret, return_stats=True)
+                q[:, 0], pool, layer, block_tables, pos, interpret=interpret,
+                return_stats=True)
             outs.append(o), ms.append(m), ls.append(l)
         kns, vns = k1s, v1s
         merged = A.merge_inflight_token(
@@ -309,7 +307,7 @@ def sharded_decode_step_paged(shards: Sequence[Params], cfg: ModelConfig,
                   jnp.arange(num_layers, dtype=jnp.int32)))
     new_pools = tuple(
         kv_append_tokens(pool, block_tables, position, ks[s], vs[s],
-                         block_size=cfg.block_size, interpret=interpret)
+                         interpret=interpret)
         for s, pool in enumerate(pools))
     x = rms_norm(x, shards[0]["final_norm"], cfg.norm_eps)
     logits = unembed(x, shards[0].get("unembed", shards[0]["embed"]))[:, 0]

@@ -10,7 +10,7 @@ per page, and the scalar-prefetched block table drives the source index of
 each page DMA, so the compiled artifact *is* the descriptor list.
 
 Block-major pool layout (paper Eq. 5) means one grid step moves a block's
-K+V for ALL layers — under the vLLM (L, 2, B, H) layout the same staging
+K+V for ALL layers — under the vLLM (L, 2, B, ...) layout the same staging
 would need L x 2 grid steps per block.
 """
 from __future__ import annotations
@@ -32,20 +32,19 @@ def _kernel(ids_ref, pool_ref, out_ref):
 
 def kv_gather(pool: jax.Array, block_ids: jax.Array, *,
               interpret: Optional[bool] = None) -> jax.Array:
-    """pool (nb, L, 2, payload); block_ids (n,) int32 -> (n, L, 2, payload)."""
-    nb, L, two, payload = pool.shape
+    """pool (nb, L, 2, KV, bs, hd); block_ids (n,) int32 -> (n, L, 2, KV, bs, hd)."""
+    block = pool.shape[1:]
+    rest = (0,) * len(block)
     n = block_ids.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, L, two, payload), lambda i, ids: (ids[i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, L, two, payload), lambda i, ids: (i, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, *block), lambda i, ids: (ids[i], *rest))],
+        out_specs=pl.BlockSpec((1, *block), lambda i, ids: (i, *rest)),
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, L, two, payload), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, *block), pool.dtype),
         interpret=interpret_mode(interpret),
     )(block_ids, pool)

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: scatter a contiguous staging buffer into FlowKV pages.
 
 The receiver-side inverse of ``kv_gather``: after a staged transfer lands as
-one contiguous buffer ``(n, L, 2, payload)``, each grid step DMAs one staged
+one contiguous buffer ``(n, L, 2, KV, bs, hd)``, each grid step DMAs one staged
 block into its local pool slot, driven by the scalar-prefetched block table.
 The pool is aliased to the output, so untouched blocks keep their contents
 without a second pool allocation.
@@ -25,17 +25,18 @@ def _kernel(ids_ref, staging_ref, pool_ref, out_ref):
 
 def kv_scatter(pool: jax.Array, block_ids: jax.Array, staging: jax.Array, *,
                interpret: Optional[bool] = None) -> jax.Array:
-    """pool (nb, L, 2, payload); block_ids (n,) int32; staging (n, L, 2, payload)."""
-    nb, L, two, payload = pool.shape
+    """pool (nb, L, 2, KV, bs, hd); block_ids (n,) int32; staging (n, L, 2, KV, bs, hd)."""
+    block = (1, *pool.shape[1:])
+    rest = (0,) * (len(block) - 1)
     n = block_ids.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, L, two, payload), lambda i, ids: (i, 0, 0, 0)),
-            pl.BlockSpec((1, L, two, payload), lambda i, ids: (ids[i], 0, 0, 0)),
+            pl.BlockSpec(block, lambda i, ids: (i, *rest)),
+            pl.BlockSpec(block, lambda i, ids: (ids[i], *rest)),
         ],
-        out_specs=pl.BlockSpec((1, L, two, payload), lambda i, ids: (ids[i], 0, 0, 0)),
+        out_specs=pl.BlockSpec(block, lambda i, ids: (ids[i], *rest)),
     )
     return pl.pallas_call(
         _kernel,

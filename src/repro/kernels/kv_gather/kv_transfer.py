@@ -6,23 +6,20 @@ page ids — and the whole plan executes as ONE kernel dispatch, regardless of
 schedule (layerwise / blockwise / flowkv). Schedules differ only in how many
 *transport calls* the cost model prices, never in Python loop structure.
 
-Both pools are viewed as page tables ``(num_pages, rows, lanes)`` where one
-page is one (block, layer, k/v) slice — the finest unit any schedule moves —
-and ``rows * lanes`` is its payload (``lanes = 128`` whenever the payload
-allows it). Each grid step moves one whole page, so the block's last two
-dims equal the array's, which is what Mosaic's (8, 128) tiling rule asks of
-a block. The two page-id tables are scalar-prefetched so the grid's index
-maps can compute each page DMA's source and destination before the body
-runs: the compiled artifact *is* the descriptor table. The destination pool
-is aliased to the output (donated under ``jax.jit``), so pages not named by
-the table keep their previous contents and no second pool allocation is
-made.
-
-Viewing a pool as pages is a relayout on the TPU (its tiled layout changes
-with the minor dims), so the views and their inverse run under the
-``pool_relayout`` named scope, and the kernel carries the caller's ``name``
-(``kv_transfer`` for a P->D plan, ``kv_append`` for the decode step's
-append): both are how the device trace attributes their time.
+A pool's trailing three dims are one page, as it is stored: one (block,
+layer, k/v) slice ``(KV, block_size, head_dim)``, the finest unit any
+schedule moves (``core/layout.py``). Both pools are viewed as page tables
+``(num_pages, KV * block_size, head_dim)``, which merges leading dims only:
+on a TPU the minor ``(block_size, head_dim)`` dims are whole tiles, so the
+view is a bitcast of the stored pool, not a copy. Each grid step moves one
+whole page, so the block's last two dims equal the array's, which is what
+Mosaic's (8, 128) tiling rule asks of a block. The two page-id tables are
+scalar-prefetched so the grid's index maps can compute each page DMA's
+source and destination before the body runs: the compiled artifact *is*
+the descriptor table. The destination pool is aliased to the output
+(donated under ``jax.jit``), so pages not named by the table keep their
+previous contents and no second pool allocation is made. The kernel is
+named ``kv_transfer`` in the compiled program and the device trace.
 """
 from __future__ import annotations
 
@@ -36,10 +33,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import interpret_mode
 
 
-def _page_view(pool: jax.Array, payload: int) -> jax.Array:
-    """``pool`` as ``(num_pages, rows, lanes)`` pages of ``payload`` elements."""
-    lanes = 128 if payload % 128 == 0 else payload
-    return pool.reshape(-1, payload // lanes, lanes)
+def _page_view(pool: jax.Array) -> jax.Array:
+    """``pool`` as ``(num_pages, rows, head_dim)``: its trailing three dims,
+    one page, with the kv-head and slot dims merged."""
+    kv, slots, hd = pool.shape[-3:]
+    return pool.reshape(-1, kv * slots, hd)
 
 
 def _kernel(src_pages_ref, dst_pages_ref, src_ref, dst_ref, out_ref):
@@ -49,24 +47,21 @@ def _kernel(src_pages_ref, dst_pages_ref, src_ref, dst_ref, out_ref):
 
 def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
                 src_pages: jax.Array, dst_pages: jax.Array, *,
-                interpret: Optional[bool] = None,
-                name: str = "kv_transfer") -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """Execute one descriptor table in one dispatch.
 
-    ``src_pool`` / ``dst_pool`` are paged KV pools in either layout — they are
-    viewed as ``(num_pages, rows, lanes)`` page tables internally, so the same
-    kernel serves FLOWKV (B, L, 2, H) and VLLM (L, 2, B, H) pools on either
-    side. ``src_pages`` / ``dst_pages`` are equal-length int32 page-id tables.
-    Returns the updated destination pool (dst is aliased to the output).
-    ``name`` names the kernel in the compiled program and the device trace.
+    ``src_pool`` / ``dst_pool`` are paged KV pools in either layout, FLOWKV
+    ``(B, L, 2, KV, bs, hd)`` or VLLM ``(L, 2, B, KV, bs, hd)``, on either
+    side; page ``p`` is the ``p``-th page in row-major order of the leading
+    dims. ``src_pages`` / ``dst_pages`` are equal-length int32 page-id
+    tables. Returns the updated destination pool (dst is aliased to the
+    output).
     """
-    payload = src_pool.shape[-1]
-    if dst_pool.shape[-1] != payload:
-        raise ValueError(
-            f"src/dst page payloads differ: {payload} vs {dst_pool.shape[-1]}")
-    with jax.named_scope("pool_relayout"):
-        src_flat = _page_view(src_pool, payload)
-        dst_flat = _page_view(dst_pool, payload)
+    if src_pool.shape[-3:] != dst_pool.shape[-3:]:
+        raise ValueError(f"src/dst pages differ: {src_pool.shape[-3:]} "
+                         f"vs {dst_pool.shape[-3:]}")
+    src_flat = _page_view(src_pool)
+    dst_flat = _page_view(dst_pool)
     page = (None, *dst_flat.shape[1:])
     n = src_pages.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -86,8 +81,7 @@ def kv_transfer(src_pool: jax.Array, dst_pool: jax.Array,
         # operand 3 and aliases output 0 (in-place pool update / donation).
         input_output_aliases={3: 0},
         interpret=interpret_mode(interpret),
-        name=name,
+        name="kv_transfer",
     )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32),
       src_flat, dst_flat)
-    with jax.named_scope("pool_relayout"):
-        return out_flat.reshape(dst_pool.shape)
+    return out_flat.reshape(dst_pool.shape)

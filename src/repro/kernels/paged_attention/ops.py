@@ -1,7 +1,7 @@
 """Jitted wrapper for the paged decode attention kernel.
 
-``paged_decode_attention_op`` takes the full FlowKV pool and a layer index,
-slices that layer's contiguous page plane, and runs the kernel. On TPU the
+``paged_decode_attention_op`` takes the full FlowKV pool and a layer index
+and runs the kernel, which reads that layer's pages in place. On TPU the
 call compiles to a Mosaic kernel; on a CPU backend the Pallas interpreter
 executes the same kernel body for correctness (tests sweep shapes/dtypes
 against ``ref.py``).
@@ -16,20 +16,16 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.paged_attention.paged_attention import paged_decode_attention
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "interpret",
-                                             "return_stats"))
+@functools.partial(jax.jit, static_argnames=("interpret", "return_stats"))
 def paged_decode_attention_op(q: jax.Array, pool: jax.Array, layer,
                               block_tables: jax.Array, lengths: jax.Array,
-                              *, block_size: int,
-                              interpret: Optional[bool] = None,
+                              *, interpret: Optional[bool] = None,
                               return_stats: bool = False):
-    """q (B,H,hd); pool (nb, L, 2, payload) FlowKV layout; layer scalar."""
-    pages = jax.lax.dynamic_index_in_dim(pool, layer, axis=1, keepdims=False)
-    return paged_decode_attention(q, pages, block_tables, lengths,
-                                  block_size=block_size, interpret=interpret,
+    """q (B,H,hd); pool (nb, L, 2, KV, bs, hd) FlowKV layout; layer scalar."""
+    return paged_decode_attention(q, pool, layer, block_tables, lengths,
+                                  interpret=interpret,
                                   return_stats=return_stats)

@@ -1,25 +1,25 @@
 """Pallas TPU kernel: decode attention over FlowKV block-major pages.
 
 This is the paper's "targeted optimizations ... for the PagedAttention
-kernel" (§3.3) adapted to TPU: the pool layout is block-major
-``(nb, L, 2, payload)`` (Eq. 5), so the kernel for one layer receives the
-contiguous slice ``pages = pool[:, layer]`` of shape ``(nb, 2, payload)``
-and *one DMA per page* stages a block's K AND V for this layer into VMEM —
-no per-(layer, k/v) descriptors, mirroring the transfer-path win.
+kernel" (§3.3) adapted to TPU. The pool is stored block-major with each
+page kv-head-major, ``(nb, L, 2, KV, block_size, hd)`` (Eq. 5, see
+``core/layout.py``), and the kernel reads it IN PLACE: it takes the whole
+pool plus the layer index, and its page BlockSpec ``(None, None, 2, KV,
+block_size, hd)`` at ``(block_tables[b, i], layer, 0, 0, 0, 0)`` makes *one
+DMA per page* stage a block's K AND V for this layer into VMEM, straight
+from the stored pool, with no per-(layer, k/v) descriptors, mirroring the
+transfer-path win.
 
-A page's payload is slot-major ``(block_size, KV, hd)``. The wrapper views
-it kv-head-major, ``(nb, 2, KV, block_size, hd)``, in XLA (where the
-transpose fuses into the layer slice) and presents ``q`` as
-``(B, KV, G, hd)``, so every block's last two dims are whole array dims
-(Mosaic's tiling rule) and both contractions batch over the leading
-kv-head axis. No reshape happens inside the kernel.
+``q`` is presented as ``(B, KV, G, hd)``, so every block's last two dims
+are whole array dims (Mosaic's tiling rule) and both contractions batch
+over the leading kv-head axis. No reshape happens inside the kernel.
 
 Grid: ``(B, max_blocks)`` — the page dim iterates sequentially (TPU minor
 grid dim), maintaining an online-softmax accumulator in VMEM scratch per
-sequence. Page indirection uses scalar-prefetched block tables in the
-BlockSpec index_map, so the pipeline prefetches page ``i+1`` while page
-``i`` is being processed (the TPU analogue of overlapping transfer kernels
-with compute).
+sequence. Page indirection uses scalar-prefetched block tables (and the
+layer index) in the BlockSpec index_map, so the pipeline prefetches page
+``i+1`` while page ``i`` is being processed (the TPU analogue of
+overlapping transfer kernels with compute).
 
 Tiling: one page block is ``(2, KV, block_size, hd)``. With the default
 32-token blocks and a 128-wide head_dim every MXU operand is lane-aligned.
@@ -44,7 +44,7 @@ from repro.kernels import interpret_mode
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
-def _kernel(block_tables_ref, lengths_ref,     # scalar prefetch
+def _kernel(block_tables_ref, lengths_ref, layer_ref,   # scalar prefetch
             q_ref, pages_ref,                  # VMEM inputs
             *refs,                             # VMEM outputs + scratch
             block_size: int, head_dim: int, return_stats: bool):
@@ -101,42 +101,42 @@ def _kernel(block_tables_ref, lengths_ref,     # scalar prefetch
             l_out_ref[...] = l_ref[...]
 
 
-def paged_decode_attention(q: jax.Array, pages: jax.Array,
+def paged_decode_attention(q: jax.Array, pool: jax.Array, layer,
                            block_tables: jax.Array, lengths: jax.Array,
-                           *, block_size: int,
-                           interpret: Optional[bool] = None,
+                           *, interpret: Optional[bool] = None,
                            return_stats: bool = False):
-    """q (B,H,hd); pages (nb,2,payload); block_tables (B,maxb); lengths (B,).
+    """q (B,H,hd); pool (nb,L,2,KV,bs,hd); layer int scalar;
+    block_tables (B,maxb); lengths (B,).
 
-    Returns ``out (B,H,hd)``; with ``return_stats=True`` returns
-    ``(out, m, l)`` where ``m``/``l`` are the fp32 online-softmax max and
-    normalizer per (B, KV, G) — ``out * l`` recovers the unnormalized
-    accumulator for exact merging with additional keys.
+    Reads layer ``layer``'s pages of the blocks in ``block_tables`` straight
+    from the pool. Returns ``out (B,H,hd)``; with ``return_stats=True``
+    returns ``(out, m, l)`` where ``m``/``l`` are the fp32 online-softmax
+    max and normalizer per (B, KV, G) — ``out * l`` recovers the
+    unnormalized accumulator for exact merging with additional keys.
     """
     b, h, hd = q.shape
     maxb = block_tables.shape[1]
-    nb, two, payload = pages.shape
-    num_kv = payload // (block_size * hd)
+    _, _, two, num_kv, block_size, _ = pool.shape
     g = h // num_kv
     qg = q.reshape(b, num_kv, g, hd)
-    pages_kv = pages.reshape(nb, two, block_size, num_kv, hd
-                             ).transpose(0, 1, 3, 2, 4)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    per_seq = lambda bb, i, bt, ln: (bb, 0, 0, 0)
+    per_seq = lambda bb, i, bt, ln, ly: (bb, 0, 0, 0)
     out_specs = [pl.BlockSpec((None, num_kv, g, hd), per_seq)]
     out_shapes = [jax.ShapeDtypeStruct((b, num_kv, g, hd), q.dtype)]
     if return_stats:
         out_specs += [pl.BlockSpec((None, num_kv, g),
-                                   lambda bb, i, bt, ln: (bb, 0, 0))] * 2
+                                   lambda bb, i, bt, ln, ly: (bb, 0, 0))] * 2
         out_shapes += [jax.ShapeDtypeStruct((b, num_kv, g), jnp.float32)] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, maxb),
         in_specs=[
             pl.BlockSpec((None, num_kv, g, hd), per_seq),
-            pl.BlockSpec((None, two, num_kv, block_size, hd),
-                         lambda bb, i, bt, ln: (bt[bb, i], 0, 0, 0, 0)),
+            pl.BlockSpec((None, None, two, num_kv, block_size, hd),
+                         lambda bb, i, bt, ln, ly: (bt[bb, i], ly[0],
+                                                    0, 0, 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -153,7 +153,7 @@ def paged_decode_attention(q: jax.Array, pages: jax.Array,
         out_shape=out_shapes,
         interpret=interpret_mode(interpret),
         name="paged_attention",
-    )(block_tables, lengths, qg, pages_kv)
+    )(block_tables, lengths, layer, qg, pool)
     out = outs[0].reshape(b, h, hd)
     if return_stats:
         return out, outs[1], outs[2]

@@ -217,28 +217,31 @@ def decode_self_attention(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfi
 
 
 def decode_paged_self_attention(p: Dict[str, jax.Array], x: jax.Array,
-                                cfg: ModelConfig, pages: jax.Array,
+                                cfg: ModelConfig, pool: jax.Array, layer,
                                 block_tables: jax.Array, position: jax.Array,
                                 *, interpret: Optional[bool] = None
                                 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Single-token decode directly against one layer's FlowKV page plane.
+    """Single-token decode directly against one layer's pages of the pool.
 
-    x (B, 1, D); pages (nb, 2, payload) — ``pool[:, layer]``; block_tables
-    (B, W) int32; position (B,) int32 = tokens already cached (the in-flight
-    token's absolute index). The cached keys are read IN PLACE by the paged
+    x (B, 1, D); pool (nb, L, 2, KV, bs, hd) FlowKV layout; layer int
+    scalar; block_tables (B, W) int32; position (B,) int32 = tokens already
+    cached (the in-flight token's absolute index). The cached keys of layer
+    ``layer`` are read IN PLACE from the whole pool by the paged
     kernel; the in-flight token — whose K/V is not in the pool yet — is
     folded in exactly via the kernel's online-softmax state (m, l), so no
     dense (B, T) cache is ever materialized. Returns
     (out (B, 1, D), (k_new (B, KV, hd), v_new (B, KV, hd))); the caller
-    appends the new K/V for the whole layer stack in one fused scatter.
+    appends the new K/V for the whole layer stack in one fused in-place
+    append.
     """
-    out, kv = decode_paged_attention_heads(p, x, cfg, pages, block_tables,
-                                           position, interpret=interpret)
+    out, kv = decode_paged_attention_heads(p, x, cfg, pool, layer,
+                                           block_tables, position,
+                                           interpret=interpret)
     return out_project(p, out), kv
 
 
 def decode_paged_attention_heads(p: Dict[str, jax.Array], x: jax.Array,
-                                 cfg: ModelConfig, pages: jax.Array,
+                                 cfg: ModelConfig, pool: jax.Array, layer,
                                  block_tables: jax.Array, position: jax.Array,
                                  *, interpret: Optional[bool] = None
                                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
@@ -246,7 +249,7 @@ def decode_paged_attention_heads(p: Dict[str, jax.Array], x: jax.Array,
 
     The paged read, the online-softmax merge of the in-flight token, and the
     normalization are all per-kv-head independent, so a TP shard runs this
-    against its own head-sliced page plane (``distributed/tp.py``).
+    against its own head-sliced pool (``distributed/tp.py``).
     """
     from repro.kernels.paged_attention import paged_decode_attention
 
@@ -254,8 +257,8 @@ def decode_paged_attention_heads(p: Dict[str, jax.Array], x: jax.Array,
     q, k_new, v_new = qkv_project(p, x, cfg, pos[:, None])
     q1, k1, v1 = q[:, 0], k_new[:, 0], v_new[:, 0]
     out_old, m_old, l_old = paged_decode_attention(
-        q1, pages, block_tables, pos, block_size=cfg.block_size,
-        interpret=interpret, return_stats=True)
+        q1, pool, layer, block_tables, pos, interpret=interpret,
+        return_stats=True)
     out = merge_inflight_token(q1, k1, v1, out_old, m_old, l_old, x.dtype)
     return out, (k1, v1)
 

@@ -278,17 +278,18 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jax.Array,
                       ) -> Tuple[jax.Array, jax.Array]:
     """One batched decode step directly on the FlowKV pool (zero-gather).
 
-    token (B,) int32; pool (nb, L, 2, payload); block_tables (B, W) int32;
-    lengths (B,) int32 = tokens already cached per request — the new token's
-    write position. Returns (logits (B, V) fp32, updated pool).
+    token (B,) int32; pool (nb, L, 2, KV, bs, hd); block_tables (B, W)
+    int32; lengths (B,) int32 = tokens already cached per request — the new
+    token's write position. Returns (logits (B, V) fp32, updated pool).
 
     Unlike :func:`decode_step`, no dense (L, B, T, KV, hd) cache is ever
-    built: every layer's attention reads pages in place through the Pallas
-    paged kernel (the in-flight token is merged via the kernel's softmax
-    state), and the batch's new K/V for ALL layers lands in one fused
-    descriptor-table scatter after the layer stack. Under ``jax.jit`` with
-    the pool donated this is one device dispatch per decode cycle,
-    independent of batch size and context length.
+    built, and the pool is never sliced, reshaped or copied: every layer's
+    attention hands the whole pool and its layer index to the Pallas paged
+    kernel, which reads that layer's pages in place (the in-flight token is
+    merged via the kernel's softmax state), and the batch's new K/V for ALL
+    layers is written in place by one fused append after the layer stack.
+    Under ``jax.jit`` with the pool donated this is one device dispatch per
+    decode cycle, independent of batch size and context length.
     """
     from repro.kernels.kv_gather import kv_append_tokens
 
@@ -300,10 +301,8 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jax.Array,
         lp, layer = inputs
         hn = rms_norm(h, lp["norm_attn"], cfg.norm_eps)
         with jax.named_scope("paged_attention"):
-            pages = jax.lax.dynamic_index_in_dim(pool, layer, axis=1,
-                                                 keepdims=False)
             attn_out, (k_new, v_new) = A.decode_paged_self_attention(
-                lp, hn, cfg, pages, block_tables, position,
+                lp, hn, cfg, pool, layer, block_tables, position,
                 interpret=interpret)
         h = h + attn_out
         hn = rms_norm(h, lp["norm_mlp"], cfg.norm_eps)
@@ -314,7 +313,7 @@ def decode_step_paged(params: Params, cfg: ModelConfig, token: jax.Array,
         body, x, (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     with jax.named_scope("kv_append"):
         pool = kv_append_tokens(pool, block_tables, position, ks, vs,
-                                block_size=cfg.block_size, interpret=interpret)
+                                interpret=interpret)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params.get("unembed", params["embed"]))[:, 0]
     return logits, pool

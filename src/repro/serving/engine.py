@@ -6,7 +6,7 @@ Two request-state transports, per DESIGN.md §4:
 * paged KV path (transformer families) — prefill writes pages; decode runs
   the ZERO-GATHER step: one jitted ``Model.decode_paged`` call per cycle
   that reads pages in place through the Pallas paged-attention kernel and
-  appends the batch's new K/V with one fused descriptor-table scatter, the
+  writes the batch's new K/V in place with one fused append kernel, the
   pool donated. No dense cache is materialized; device dispatches per
   decode cycle are O(1) regardless of batch size or context length. The
   old gather-dense bridge survives as the test/benchmark oracle
@@ -15,8 +15,8 @@ Two request-state transports, per DESIGN.md §4:
   whole and shipped whole (one logical segment).
 
 Ragged batches are padded to power-of-two buckets in BOTH batch size and
-block-table width (pad lanes replicate lane 0, so their duplicate append
-descriptors are idempotent), keeping the jit cache bounded at
+block-table width (pad lanes replicate lane 0, so their duplicate page
+rewrites are idempotent), keeping the jit cache bounded at
 ``O(log2(max_batch) * log2(max_blocks))`` variants. ``decode_dispatches`` /
 ``decode_steps`` / ``decode_compile_variants`` surface through
 ``RequestHandle.stats()`` and ``PDCluster.stats()``.

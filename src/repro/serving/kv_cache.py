@@ -1,15 +1,17 @@
 """Device-side paged KV cache in the FlowKV block-major layout.
 
-The pool is ONE array ``(num_blocks, L, 2, payload)`` (paper Eq. 5) so a
-request's KV for all layers lives in its blocks contiguously — the transfer
-engine moves whole block ranges with single calls. The control plane
-(which blocks belong to whom) is ``core.block_manager.BlockManager``.
+The pool is ONE array ``(num_blocks, L, 2, KV, block_size, head_dim)``
+(paper Eq. 5, pages stored kv-head-major as the kernels read them; see
+``core/layout.py``) so a request's KV for all layers lives in its blocks
+contiguously — the transfer engine moves whole block ranges with single
+calls. The control plane (which blocks belong to whom) is
+``core.block_manager.BlockManager``.
 
 ``write_prefill`` / ``gather_dense`` / ``append_token`` bridge between the
 model's dense cache format (L, S, KV, hd) and pages. At serving time the
 decode plane does NOT use the bridge: ``models/transformer.decode_step_paged``
 reads pages in place through ``kernels/paged_attention`` and appends the
-batch's new K/V with one fused scatter (``export_block_tables`` /
+batch's new K/V with one fused in-place update (``export_block_tables`` /
 ``append_tokens`` are its host-side ports). The dense bridge here is the
 reference data path — the oracle the paged step is tested against.
 
@@ -86,12 +88,11 @@ class PagedKVCache:
         if pad:
             k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        L = spec.num_layers
-        # (L, nb, bs, KV, hd) -> (nb, L, bs*KV*hd)
-        kp = k.reshape(L, nb, spec.block_size, -1).transpose(1, 0, 2, 3).reshape(nb, L, -1)
-        vp = v.reshape(L, nb, spec.block_size, -1).transpose(1, 0, 2, 3).reshape(nb, L, -1)
+        # (L, S, KV, hd) x2 -> (nb, L, 2, KV, bs, hd)
+        kv = jnp.stack([k, v], axis=1).reshape(
+            spec.num_layers, 2, nb, spec.block_size, spec.num_kv_heads,
+            spec.head_dim).transpose(2, 0, 1, 4, 3, 5).astype(spec.dtype)
         idx = jnp.asarray(blocks[:nb], jnp.int32)
-        kv = jnp.stack([kp, vp], axis=2).astype(spec.dtype)   # (nb, L, 2, payload)
         self.pool = self.pool.at[idx].set(kv)
         self.num_pool_dispatches += 1
         return blocks[:nb]
@@ -108,11 +109,8 @@ class PagedKVCache:
         blocks = self.bm.get(request_id)
         block = blocks[position // spec.block_size]
         slot = position % spec.block_size
-        L = spec.num_layers
-        pv = self.pool[block].reshape(L, 2, spec.block_size, -1)
-        pv = pv.at[:, 0, slot].set(k_new.reshape(L, -1).astype(spec.dtype))
-        pv = pv.at[:, 1, slot].set(v_new.reshape(L, -1).astype(spec.dtype))
-        self.pool = self.pool.at[block].set(pv.reshape(L, 2, -1))
+        kv = jnp.stack([k_new, v_new], axis=1).astype(spec.dtype)  # (L, 2, KV, hd)
+        self.pool = self.pool.at[block, :, :, :, slot].set(kv)
         self.num_pool_dispatches += 1
 
     def append_tokens(self, request_ids: Sequence[int], k_new: jax.Array,
@@ -126,8 +124,7 @@ class PagedKVCache:
         tables = self.export_block_tables(request_ids)
         pos = jnp.asarray(list(positions), jnp.int32)
         self.pool = kv_append_tokens(self.pool, jnp.asarray(tables), pos,
-                                     k_new, v_new,
-                                     block_size=self.spec.block_size)
+                                     k_new, v_new)
         self.num_pool_dispatches += 1
 
     # -- read path ---------------------------------------------------------------
@@ -161,15 +158,11 @@ class PagedKVCache:
         if num_blocks is not None:
             blocks = blocks[:num_blocks]
         idx = jnp.asarray(blocks, jnp.int32)
-        pages = jnp.take(self.pool, idx, axis=0)          # (nb, L, 2, payload)
+        pages = jnp.take(self.pool, idx, axis=0)          # (nb, L, 2, KV, bs, hd)
         self.num_pool_dispatches += 1
-        nb = pages.shape[0]
-        L = spec.num_layers
-        pages = pages.reshape(nb, L, 2, spec.block_size, spec.num_kv_heads, spec.head_dim)
-        k = pages[:, :, 0].transpose(1, 0, 2, 3, 4).reshape(L, nb * spec.block_size,
-                                                            spec.num_kv_heads, spec.head_dim)
-        v = pages[:, :, 1].transpose(1, 0, 2, 3, 4).reshape(L, nb * spec.block_size,
-                                                            spec.num_kv_heads, spec.head_dim)
+        shape = (spec.num_layers, -1, spec.num_kv_heads, spec.head_dim)
+        k = pages[:, :, 0].transpose(1, 0, 3, 2, 4).reshape(shape)
+        v = pages[:, :, 1].transpose(1, 0, 3, 2, 4).reshape(shape)
         cur = k.shape[1]
         if cur < max_len:
             k = jnp.pad(k, ((0, 0), (0, max_len - cur), (0, 0), (0, 0)))
@@ -203,8 +196,8 @@ class ShardedKVCache:
     """``tp`` per-shard pools over ONE block manager (mesh-parallel pool).
 
     Shard ``s`` holds the FLOWKV pool for its contiguous kv-head slice —
-    same ``(num_blocks, L, 2, ·)`` geometry, payload ``block_size *
-    (num_kv_heads/tp) * head_dim``. Page ids are GLOBAL: one BlockManager
+    same ``(num_blocks, L, 2, ·, block_size, head_dim)`` geometry with
+    ``num_kv_heads/tp`` heads on axis 3. Page ids are GLOBAL: one BlockManager
     allocates for all shards (a request's block i is block i in every
     shard's pool), which is what lets a cross-degree transfer plan address
     both sides with one descriptor table (core/transfer.ShardedTransferEngine)

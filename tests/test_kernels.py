@@ -23,32 +23,103 @@ SWEEP_PAGED = [
 @pytest.mark.parametrize("b,h,kv,hd,bs,maxb,dtype", SWEEP_PAGED)
 def test_paged_attention_sweep(b, h, kv, hd, bs, maxb, dtype):
     key = jax.random.PRNGKey(0)
-    nb = b * maxb + 4
+    nb, L, layer = b * maxb + 4, 3, 1
     q = jax.random.normal(key, (b, h, hd), dtype)
-    pages = jax.random.normal(jax.random.PRNGKey(1), (nb, 2, bs * kv * hd), dtype)
+    pool = jax.random.normal(jax.random.PRNGKey(1), (nb, L, 2, kv, bs, hd), dtype)
     bt = jax.random.permutation(jax.random.PRNGKey(2), nb)[:b * maxb]
     bt = bt.reshape(b, maxb).astype(jnp.int32)
     lengths = jax.random.randint(jax.random.PRNGKey(3), (b,), 1, maxb * bs + 1)
-    out = paged_decode_attention(q, pages, bt, lengths, block_size=bs)
-    ref = paged_decode_attention_ref(q, pages, bt, lengths, bs)
+    out = paged_decode_attention(q, pool, layer, bt, lengths)
+    ref = paged_decode_attention_ref(q, pool, layer, bt, lengths)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
 
 
 def test_paged_attention_op_layer_slice():
-    """ops wrapper slices one layer from the full FlowKV pool."""
+    """The kernel reads each layer's pages of the full FlowKV pool in place."""
     b, h, kv, hd, bs, maxb, L = 2, 4, 2, 16, 4, 3, 3
     nb = 16
-    pool = jax.random.normal(jax.random.PRNGKey(0), (nb, L, 2, bs * kv * hd))
+    pool = jax.random.normal(jax.random.PRNGKey(0), (nb, L, 2, kv, bs, hd))
     q = jax.random.normal(jax.random.PRNGKey(1), (b, h, hd))
     bt = jnp.arange(b * maxb, dtype=jnp.int32).reshape(b, maxb)
     lengths = jnp.asarray([7, 12], jnp.int32)
+    outs = []
     for layer in range(L):
-        out = paged_decode_attention_op(q, pool, layer, bt, lengths, block_size=bs)
-        ref = paged_decode_attention_ref(q, pool[:, layer], bt, lengths, bs)
+        out = paged_decode_attention_op(q, pool, layer, bt, lengths)
+        ref = paged_decode_attention_ref(q, pool, layer, bt, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+        outs.append(np.asarray(out))
+    assert not np.allclose(outs[0], outs[1])     # layers are told apart
+
+
+# One tile-native shape: head_dim 128 and 16-token blocks, so a page's
+# minor dims are whole (16, 128) bf16 tiles, as at the served widths.
+NATIVE = dict(nb=8, L=2, kv=2, bs=16, hd=128)
+
+
+@pytest.mark.parametrize("return_stats", [False, True])
+def test_paged_attention_tile_native_shape(return_stats):
+    from repro.kernels.paged_attention import paged_decode_attention_stats_ref
+    nb, L, kv, bs, hd = (NATIVE[k] for k in ("nb", "L", "kv", "bs", "hd"))
+    b, h, maxb = 2, 4, 3
+    pool = jax.random.normal(jax.random.PRNGKey(0), (nb, L, 2, kv, bs, hd),
+                             jnp.bfloat16)
+    q = jax.random.normal(jax.random.PRNGKey(1), (b, h, hd), jnp.bfloat16)
+    bt = jnp.asarray([[5, 0, 3], [7, 2, 6]], jnp.int32)
+    lengths = jnp.asarray([40, 17], jnp.int32)
+    for layer in range(L):
+        got = paged_decode_attention(q, pool, layer, bt, lengths,
+                                     return_stats=return_stats)
+        if return_stats:
+            ref = paged_decode_attention_stats_ref(q, pool, layer, bt, lengths)
+        else:
+            got, ref = (got,), (paged_decode_attention_ref(
+                q, pool, layer, bt, lengths),)
+        for g, r in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(g, np.float32),
+                                       np.asarray(r, np.float32),
+                                       rtol=2e-2, atol=2e-2)
+
+
+def test_kv_append_tile_native_shape():
+    from repro.kernels.kv_gather import kv_append_ref, kv_append_tokens
+    nb, L, kv, bs, hd = (NATIVE[k] for k in ("nb", "L", "kv", "bs", "hd"))
+    pool = jax.random.normal(jax.random.PRNGKey(0), (nb, L, 2, kv, bs, hd),
+                             jnp.bfloat16)
+    # lane 2 pads the batch by replicating lane 0, as the engine does
+    bt = jnp.asarray([[4, 1], [6, 3], [4, 1]], jnp.int32)
+    pos = jnp.asarray([21, 15, 21], jnp.int32)
+    k_new = jax.random.normal(jax.random.PRNGKey(1), (L, 3, kv, hd), jnp.bfloat16)
+    v_new = jax.random.normal(jax.random.PRNGKey(2), (L, 3, kv, hd), jnp.bfloat16)
+    k_new, v_new = k_new.at[:, 2].set(k_new[:, 0]), v_new.at[:, 2].set(v_new[:, 0])
+    out = kv_append_tokens(pool, bt, pos, k_new, v_new)
+    ref = kv_append_ref(pool, bt, pos, k_new, v_new)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(ref, np.float32))
+    changed = np.argwhere(np.any(np.asarray(out != pool), axis=(3, 5)))
+    assert {tuple(c) for c in changed} == {
+        (blk, l, k, slot) for blk, slot in ((1, 5), (6, 15))
+        for l in range(L) for k in range(2)}
+
+
+def test_kv_transfer_tile_native_shape():
+    from repro.kernels.kv_gather import kv_transfer, kv_transfer_ref
+    nb, L, kv, bs, hd = (NATIVE[k] for k in ("nb", "L", "kv", "bs", "hd"))
+    shape = (nb, L, 2, kv, bs, hd)
+    src = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.bfloat16)
+    dst = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.bfloat16)
+    sp = jnp.asarray([0, 13, 7, 31, 22], jnp.int32)
+    dp = jnp.asarray([30, 2, 17, 9, 0], jnp.int32)
+    out = kv_transfer(src, dst, sp, dp)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(kv_transfer_ref(src, dst, sp, dp), np.float32))
+    pages = np.asarray(out, np.float32).reshape(-1, kv, bs, hd)
+    np.testing.assert_array_equal(
+        pages[[30, 2, 17, 9, 0]],
+        np.asarray(src, np.float32).reshape(-1, kv, bs, hd)[[0, 13, 7, 31, 22]])
 
 
 SWEEP_FLASH = [
@@ -86,7 +157,7 @@ def test_flash_prefill_op_pads():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n", [1, 5, 16])
 def test_kv_gather_sweep(dtype, n):
-    pool = jax.random.normal(jax.random.PRNGKey(0), (32, 3, 2, 64), dtype)
+    pool = jax.random.normal(jax.random.PRNGKey(0), (32, 3, 2, 2, 4, 8), dtype)
     ids = jax.random.randint(jax.random.PRNGKey(1), (n,), 0, 32)
     out = kv_gather(pool, ids.astype(jnp.int32))
     np.testing.assert_array_equal(np.asarray(out, np.float32),
@@ -94,7 +165,7 @@ def test_kv_gather_sweep(dtype, n):
 
 
 def test_kv_gather_scatter_roundtrip():
-    pool = jax.random.normal(jax.random.PRNGKey(0), (32, 2, 2, 16))
+    pool = jax.random.normal(jax.random.PRNGKey(0), (32, 2, 2, 1, 4, 4))
     ids = jnp.asarray([4, 9, 30], jnp.int32)
     staged = kv_gather_op(pool, ids)
     dst = jnp.zeros_like(pool)
@@ -106,8 +177,8 @@ def test_kv_gather_scatter_roundtrip():
 @pytest.mark.parametrize("n", [1, 5, 16])
 def test_kv_scatter_sweep(dtype, n):
     from repro.kernels.kv_gather import kv_scatter, kv_scatter_ref
-    pool = jax.random.normal(jax.random.PRNGKey(0), (32, 3, 2, 64), dtype)
-    staging = jax.random.normal(jax.random.PRNGKey(1), (n, 3, 2, 64), dtype)
+    pool = jax.random.normal(jax.random.PRNGKey(0), (32, 3, 2, 2, 4, 8), dtype)
+    staging = jax.random.normal(jax.random.PRNGKey(1), (n, 3, 2, 2, 4, 8), dtype)
     ids = jax.random.permutation(jax.random.PRNGKey(2), 32)[:n].astype(jnp.int32)
     out = kv_scatter(pool, ids, staging)
     ref = kv_scatter_ref(pool, ids, staging)
@@ -146,8 +217,8 @@ def test_kv_gather_scatter_roundtrip_layouts(layout):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kv_transfer_matches_ref(dtype):
     from repro.kernels.kv_gather import kv_transfer, kv_transfer_ref
-    src = jax.random.normal(jax.random.PRNGKey(0), (10, 2, 2, 32), dtype)
-    dst = jax.random.normal(jax.random.PRNGKey(1), (8, 2, 2, 32), dtype)
+    src = jax.random.normal(jax.random.PRNGKey(0), (10, 2, 2, 2, 4, 4), dtype)
+    dst = jax.random.normal(jax.random.PRNGKey(1), (8, 2, 2, 2, 4, 4), dtype)
     sp = jnp.asarray([0, 13, 7, 39, 22], jnp.int32)   # flat page ids
     dp = jnp.asarray([31, 2, 17, 9, 0], jnp.int32)
     out = kv_transfer(src, dst, sp, dp)

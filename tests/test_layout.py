@@ -15,8 +15,11 @@ def _spec(layout):
 def test_layout_shapes_and_counts():
     fk = _spec(L.KVLayout.FLOWKV)
     vl = _spec(L.KVLayout.VLLM)
-    assert fk.shape == (10, 4, 2, 64)
-    assert vl.shape == (4, 2, 10, 64)
+    # pages stored kv-head-major (KV, bs, hd); payload is still one page
+    assert fk.shape == (10, 4, 2, 2, 4, 8)
+    assert vl.shape == (4, 2, 10, 2, 4, 8)
+    assert fk.page_shape == vl.page_shape == (2, 4, 8)
+    assert fk.payload == vl.payload == 64
     assert fk.transfer_calls_per_block() == 1
     assert vl.transfer_calls_per_block() == 8        # L*2, the paper's factor
     assert fk.bytes_per_block == vl.bytes_per_block
@@ -43,6 +46,9 @@ def test_write_read_block(layout):
     k2, v2 = L.read_block(cache, spec, 3, 2)
     np.testing.assert_allclose(np.asarray(k2), np.asarray(k))
     np.testing.assert_allclose(np.asarray(v2), np.asarray(v))
+    # stored kv-head-major: head h's plane holds that head's slots in order
+    page = cache[3, 2, 0] if layout is L.KVLayout.FLOWKV else cache[2, 0, 3]
+    np.testing.assert_array_equal(np.asarray(page[1]), np.asarray(k[:, 1]))
 
 
 @pytest.mark.parametrize("layout", [L.KVLayout.FLOWKV, L.KVLayout.VLLM])
@@ -52,7 +58,7 @@ def test_gather_scatter_blocks(layout):
     cache = jnp.asarray(rng.randn(*spec.shape), jnp.float32)
     ids = [7, 2, 5]
     payload = L.gather_blocks(cache, spec, ids)
-    assert payload.shape == (3, 4, 2, 64)
+    assert payload.shape == (3, 4, 2, 2, 4, 8)
     dst = L.alloc_cache(spec)
     dst = L.scatter_blocks(dst, spec, [1, 3, 9], payload)
     p2 = L.gather_blocks(dst, spec, [1, 3, 9])

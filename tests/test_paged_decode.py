@@ -73,6 +73,105 @@ def test_paged_decode_matches_monolithic_reference(small_model):
     assert s["mean_decode_dispatches_per_step"] == 1.0
 
 
+@pytest.mark.parametrize("tp", [1, 2])
+def test_paged_decode_matches_dense_step_sharded_and_spilled(small_model, tp):
+    """n-step paged decode equals the dense ``decode_step`` in float32 on
+    tp-sharded pools, across a spill and restore: three blocks force one
+    request off the pool mid-decode (gather_dense out, write_prefill back)."""
+    from repro.serving.api import FlowKVClient
+    from repro.serving.request import RequestState
+    cfg, params = small_model
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(0, cfg.vocab_size, size=20).tolist()
+               for _ in range(2)]
+    client = FlowKVClient(cfg, params, num_prefill=1, num_decode=1,
+                          num_blocks=3, paged_decode="kernel",
+                          tp_degrees={0: tp, 1: tp})
+    handles = [client.submit(p, SamplingParams(max_new_tokens=20))
+               for p in prompts]
+    swapped = 0
+    for _ in range(400):
+        client.step()
+        swapped += sum(h.request.state is RequestState.SWAPPED
+                       for h in handles)
+        if all(h.done for h in handles):
+            break
+    assert swapped > 0, "pool was never pressured into a spill"
+    for h, p in zip(handles, prompts):
+        ref = T.greedy_generate(params, cfg, jnp.asarray([p], jnp.int32), 20)
+        assert h.request.output_tokens == [int(x) for x in ref[0]]
+    d = client.cluster.engines[1]
+    assert d.use_paged_decode and d.decode_dispatches == d.decode_steps > 0
+
+
+def _pool_consumers(jaxpr, pool_vars, found):
+    """Walk ``jaxpr`` (into sub-jaxprs) recording every equation that takes
+    a pool value, as ``(primitive, kernel name or None)``. The outputs of
+    the in-place append are pool values too. Returns the pool values."""
+    from jax.extend.core import Literal
+    for eqn in jaxpr.eqns:
+        taken = [i for i, v in enumerate(eqn.invars)
+                 if not isinstance(v, Literal) and v in pool_vars]
+        if not taken:
+            continue
+        sub = eqn.params.get("jaxpr")
+        if eqn.primitive.name != "pallas_call" and sub is not None:
+            inner = getattr(sub, "jaxpr", sub)
+            inner_pool = _pool_consumers(
+                inner, {inner.invars[i] for i in taken}, found)
+            pool_vars |= {o for o, i in zip(eqn.outvars, inner.outvars)
+                          if i in inner_pool}
+            continue
+        name = (eqn.params["name"]
+                if eqn.primitive.name == "pallas_call" else None)
+        found.append((eqn.primitive.name, name))
+        if name == "kv_append":
+            pool_vars |= set(eqn.outvars)
+    return pool_vars
+
+
+def _all_eqns(jaxpr):
+    from jax.extend.core import Jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if isinstance(inner, Jaxpr):
+                    yield from _all_eqns(inner)
+
+
+def test_decode_step_reads_and_writes_the_pool_in_place(small_model):
+    """Structural guard: in the traced paged decode step the pool reaches
+    only the paged-attention kernel and the append's in-place write — no
+    reshape, transpose, slice, dynamic_slice or gather of it — and no
+    equation makes an array of one layer plane's size or more besides the
+    pool itself."""
+    cfg, params = small_model
+    nb, b, w = 64, 2, 4
+    spec = spec_for_model(cfg, nb)
+    plane = nb * 2 * spec.payload
+    assert plane > max(x.size for x in jax.tree.leaves(params))
+    pool = jnp.zeros(spec.shape, spec.dtype)
+    bt = jnp.arange(b * w, dtype=jnp.int32).reshape(b, w)
+    closed = jax.make_jaxpr(
+        lambda p, t, pl, bt_, ln: T.decode_step_paged(p, cfg, t, pl, bt_, ln))(
+        params, jnp.zeros((b,), jnp.int32), pool, bt,
+        jnp.asarray([40, 70], jnp.int32))
+    jaxpr = closed.jaxpr
+    pool_var = jaxpr.invars[len(jax.tree.leaves(params)) + 1]
+    assert pool_var.aval.shape == spec.shape
+    found = []
+    _pool_consumers(jaxpr, {pool_var}, found)
+    assert sorted(set(found)) == [("pallas_call", "kv_append"),
+                                  ("pallas_call", "paged_attention")], found
+    assert found.count(("pallas_call", "kv_append")) == 1
+    big = [(e.primitive.name, o.aval.shape) for e in _all_eqns(jaxpr)
+           for o in e.outvars
+           if o.aval.size >= plane and o.aval.shape != spec.shape]
+    assert not big, big
+
+
 def test_paged_decode_moe_family_parity():
     """The zero-gather step covers every paged family, not just dense."""
     cfg = get_smoke_config("granite-moe-1b-a400m")

@@ -151,20 +151,14 @@ def _full_pool(seed):
 
 
 def _shard_pool(pool, tp):
-    """Slice a full-width FLOWKV pool into per-shard kv-head slices."""
-    nb, L = _SPEC.num_blocks, _SPEC.num_layers
-    bs, kv, hd = _SPEC.block_size, _SPEC.num_kv_heads, _SPEC.head_dim
-    kw = kv // tp
-    six = np.asarray(pool).reshape(nb, L, 2, bs, kv, hd)
-    return [jnp.asarray(six[..., s * kw:(s + 1) * kw, :].reshape(nb, L, 2, -1))
+    """Slice a full-width FLOWKV pool into per-shard kv-head slices (axis 3)."""
+    kw = _SPEC.num_kv_heads // tp
+    return [jnp.asarray(np.asarray(pool)[:, :, :, s * kw:(s + 1) * kw])
             for s in range(tp)]
 
 
 def _unshard_pool(pools, tp):
-    nb, L = _SPEC.num_blocks, _SPEC.num_layers
-    bs, kv, hd = _SPEC.block_size, _SPEC.num_kv_heads, _SPEC.head_dim
-    six = [np.asarray(p).reshape(nb, L, 2, bs, kv // tp, hd) for p in pools]
-    return np.concatenate(six, axis=4).reshape(_SPEC.shape)
+    return np.concatenate([np.asarray(p) for p in pools], axis=3)
 
 
 @pytest.mark.parametrize("tp_src,tp_dst", [(2, 1), (1, 2), (2, 4), (4, 2),

@@ -58,12 +58,12 @@ def test_paged_pool_never_shards_pages():
     mesh = _fake_mesh((16, 16), ("data", "model"))
     # num_blocks=4096 divides model=16: under the kv_seq fallback this dim
     # WOULD shard — kv_pages pins it replicated
-    spec = SH.spec_for((4096, 32, 2, 16384), SH.PAGED_POOL_AXES, mesh)
+    spec = SH.spec_for((4096, 32, 2, 8, 32, 128), SH.PAGED_POOL_AXES, mesh)
     assert spec == P()
     # misdeclaring the page dim as kv_seq is exactly the regression guarded
     # against: it silently splits the page address space
-    bad = SH.spec_for((4096, 32, 2, 16384),
-                      ("kv_seq", "layers", None, None), mesh)
+    bad = SH.spec_for((4096, 32, 2, 8, 32, 128),
+                      ("kv_seq", "layers", None, None, None, None), mesh)
     assert bad == P("model")
     # the declared "kv_pages" rule must exist and be an empty candidate list
     # (intent recorded, not merely absent)

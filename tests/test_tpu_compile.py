@@ -15,14 +15,14 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.core.transfer import ShardSpec
+from repro.core.transfer import ShardSpec, head_plane_view
 from repro.kernels.kv_gather import kv_append_tokens, kv_transfer
 from repro.kernels.paged_attention import paged_decode_attention
 
 CFG = get_config("qwen3-1.7b")
 L, KV, HD, BS = CFG.num_layers, CFG.num_kv_heads, CFG.head_dim, CFG.block_size
 NB = 256                 # pool blocks (8,192 tokens)
-PAYLOAD = BS * KV * HD   # one (block, layer, k/v) page
+POOL = (NB, L, 2, KV, BS, HD)   # FLOWKV pool, pages stored kv-head-major
 B, W = 4, 128            # decode batch and block-table width
 
 
@@ -58,7 +58,7 @@ def _assert_mosaic(fn, *args):
 
 
 def test_kv_transfer_flowkv_pool_compiles(one_chip):
-    pool = _spec((NB, L, 2, PAYLOAD), jnp.bfloat16, one_chip)
+    pool = _spec(POOL, jnp.bfloat16, one_chip)
     pages = _spec((3 * L * 2,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda s, d, sp, dp: kv_transfer(s, d, sp, dp, interpret=False),
@@ -68,8 +68,8 @@ def test_kv_transfer_flowkv_pool_compiles(one_chip):
 def test_kv_append_tokens_compiles(one_chip):
     _assert_mosaic(
         lambda pool, bt, pos, k, v: kv_append_tokens(
-            pool, bt, pos, k, v, block_size=BS, interpret=False),
-        _spec((NB, L, 2, PAYLOAD), jnp.bfloat16, one_chip),
+            pool, bt, pos, k, v, interpret=False),
+        _spec(POOL, jnp.bfloat16, one_chip),
         _spec((B, W), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip),
         _spec((L, B, KV, HD), jnp.bfloat16, one_chip),
@@ -78,25 +78,23 @@ def test_kv_append_tokens_compiles(one_chip):
 
 def test_paged_decode_attention_stats_compiles(one_chip):
     _assert_mosaic(
-        lambda q, pages, bt, ln: paged_decode_attention(
-            q, pages, bt, ln, block_size=BS, interpret=False,
-            return_stats=True),
+        lambda q, pool, bt, ln: paged_decode_attention(
+            q, pool, L - 1, bt, ln, interpret=False, return_stats=True),
         _spec((B, CFG.num_heads, HD), jnp.bfloat16, one_chip),
-        _spec((NB, 2, PAYLOAD), jnp.bfloat16, one_chip),
+        _spec(POOL, jnp.bfloat16, one_chip),
         _spec((B, W), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip))
 
 
 def test_sharded_fine_row_transfer_compiles(one_chip):
-    # tp=2 shard pools moved through the fine (-1, head_dim) row view, as
+    # tp=2 shard pools moved through the head-plane view, as
     # ShardedTransferEngine.execute lowers a cross-degree plan
     shard = ShardSpec(2, KV)
-    pool = _spec((NB, L, 2, BS * shard.heads_per_shard * HD), jnp.bfloat16,
+    pool = _spec((NB, L, 2, shard.heads_per_shard, BS, HD), jnp.bfloat16,
                  one_chip)
-    rows = _spec((3 * L * 2 * BS * shard.heads_per_shard,), jnp.int32,
-                 one_chip)
+    planes = _spec((3 * L * 2 * shard.heads_per_shard,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda s, d, sr, dr: kv_transfer(
-            s.reshape(-1, HD), d.reshape(-1, HD), sr, dr,
+            head_plane_view(s), head_plane_view(d), sr, dr,
             interpret=False).reshape(d.shape),
-        pool, pool, rows, rows)
+        pool, pool, planes, planes)
